@@ -34,6 +34,8 @@ def main() -> None:
                     help="also write the engine/* rows (the perf "
                          "trajectory the CI tracks) as a JSON file")
     args = ap.parse_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     rows = []
     if args.only in (None, "claims"):

@@ -4,7 +4,7 @@ Complements the post-compile HLO view (``hlo_audit``): the jaxpr is
 available before XLA ever runs, carries exact ``lax.scan`` trip counts
 (where HLO needs while-condition parsing), and still shows structure the
 compiler later fuses away.  The walker recurses through every sub-jaxpr
-(pjit / scan / while / cond / shard_map / custom_* calls) and reports:
+(jit / scan / while / cond / shard_map / custom_* calls) and reports:
 
 - **collectives** — ``psum`` / ``all_gather`` / ``ppermute`` / ... with
   their axis names, per-shard payload aval and loop multiplier (product
@@ -30,7 +30,7 @@ from dataclasses import dataclass, field
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 COLLECTIVE_PRIMS = {
     "psum", "pmax", "pmin", "ppermute", "pshuffle", "all_gather",
@@ -41,7 +41,11 @@ DRAW_PRIMS = {"random_bits", "random_gamma", "threefry2x32"}
 KEY_TRANSPORT_PRIMS = {"random_wrap", "random_unwrap", "copy",
                        "convert_element_type"}
 HOST_SYNC_PRIMS = {"pure_callback", "io_callback", "debug_callback",
-                   "callback", "infeed", "outfeed"}
+                   "debug_print", "infeed", "outfeed"}
+# primitives that call one sub-jaxpr with their operands (jax.jit,
+# closed_call, jax.checkpoint, custom derivatives, shard_map)
+CALL_PRIMS = {"jit", "closed_call", "remat2", "custom_jvp_call",
+              "custom_vjp_call", "shard_map"}
 
 
 @dataclass(frozen=True)
@@ -221,10 +225,7 @@ class _Walker:
         if not subs:
             return
         name = eqn.primitive.name
-        if name in ("pjit", "closed_call", "core_call", "xla_call",
-                    "remat", "remat2", "checkpoint", "shard_map",
-                    "custom_jvp_call", "custom_vjp_call",
-                    "custom_vjp_call_jaxpr"):
+        if name in CALL_PRIMS:
             for _, sub, _consts in subs[:1]:
                 for outer, inner in zip(eqn.invars, sub.invars):
                     self._alias(inner, self._root(outer, weight))

@@ -344,8 +344,10 @@ def make_logistic(key, *, num_workers: int = 16, per_worker: int = 128,
     hess_f = jax.hessian(prob.loss)
     for _ in range(30):
         x = x - jnp.linalg.solve(hess_f(x), grad_f(x))
-    H = hess_f(x)
-    w = jnp.linalg.eigvalsh(H)
+    # extreme eigenvalues on the host, in float64: the TPU's eigh does not
+    # compile at d in the thousands (at d = 2048 its compile alone needs
+    # more than 10 GiB of host memory)
+    w = np.linalg.eigvalsh(np.asarray(hess_f(x), np.float64))
     return Logistic(X=X, y=y, lam=lam, grad_noise=grad_noise,
                     hess_noise=hess_noise, x_star=x,
                     mu=float(w[0]), L_g=float(w[-1]))

@@ -227,7 +227,7 @@ def _sharded_projection_fn(mesh, axis_name: str, n_model: int,
     """Compiled shard_map'd projection, cached per (mesh, axis, iters) so
     repeated calls (benchmarks, multi-problem sweeps) don't re-trace; μ
     rides as a traced scalar."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(a_panel, mu):
@@ -235,7 +235,7 @@ def _sharded_projection_fn(mesh, axis_name: str, n_model: int,
                                      n_model=n_model, num_iters=num_iters)
 
     fn = shard_map(body, mesh=mesh, in_specs=(P(axis_name, None), P()),
-                   out_specs=P(axis_name, None), check_rep=False)
+                   out_specs=P(axis_name, None), check_vma=False)
     return jax.jit(fn)
 
 

@@ -106,8 +106,8 @@ from dataclasses import dataclass, replace as dc_replace
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .aggregation import late_fold_updates, quorum_aggregate, \
     server_aggregate
@@ -1026,13 +1026,13 @@ def _sharded_engine(problem, k_loop, x1, C0, cho_c, hdiag, cost, *, mesh,
                 P(waxis, None), _replicated_specs(cho_c),
                 _replicated_specs(hdiag), _replicated_specs(cost))
     # outputs are replicated by construction (every x-update flows through
-    # the psum); check_rep=False because the replication checker cannot
+    # the psum); check_vma=False because the replication checker cannot
     # track the axis_index-based worker slicing.  Hier: the per-pod
     # iterates stack along the pod axis; everything else stays replicated.
     out_specs = ((P(None, pod_axis, None),) + (P(),) * 8
                  if hspec is not None else (P(),) * 9)
     fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     return fn(problem, k_loop, x1, C0, cho_c, hdiag, cost)
 
 
@@ -1612,7 +1612,7 @@ def _sharded2d_engine(problem, k_loop, x1, C0, hdiag, cost, *, mesh,
     out_specs = ((P(None, pod_axis, None),) + (P(),) * 8
                  if hspec is not None else (P(),) * 9)
     fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     return fn(problem, k_loop, x1, C0, hdiag, cost)
 
 
@@ -1721,7 +1721,7 @@ def _sharded2d_dense_engine(problem, key, cost, *, mesh, data_axis,
     out_specs = ((P(None, pod_axis, None),) + (P(),) * 8
                  if hspec is not None else (P(),) * 9)
     fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     return fn(problem, key, cost)
 
 
@@ -2036,6 +2036,12 @@ def _run_batch(problem, keys, opts: RanlOptions, *, mesh=None,
             raise ValueError(
                 f"batch of {keys.shape[0]} seeds must divide evenly "
                 f"across the {n_dev} devices of the {axis_name!r} axis")
+        # the seed axis is sharded by placement alone, which needs Auto
+        # axes: on an Explicit-axis mesh (``jax.make_mesh``'s default) the
+        # sharding would enter the traced types and the vmapped rounds
+        # could not resolve it
+        mesh = Mesh(mesh.devices, mesh.axis_names,
+                    axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         keys = jax.device_put(keys, NamedSharding(mesh, P(axis_name)))
         problem = jax.device_put(problem, NamedSharding(mesh, P()))
         cost = jax.device_put(cost, NamedSharding(mesh, P()))

@@ -11,6 +11,13 @@ kernel reads G/M/C once and writes g/C_new once: HBM traffic drops from
 Grid: 1-D over D blocks.  Block shape (N, BLOCK_D) with BLOCK_D a multiple
 of 128 (lane dimension); the worker dimension N (≤ 32) rides the sublane
 axis, so reductions over workers are cheap vector-unit column sums.
+
+The length-D operands (params, the curvature diagonal, the aggregated
+gradient) are carried as (1, D) rows with (1, BLOCK_D) blocks.  A 1-D
+f32 array gets XLA's T(1024) tiling on TPU, which a 1-D block of any
+other size cannot match (Mosaic refuses the kernel); a row takes the
+same 2-D tiling as the (N, D) operands, so any lane-multiple block
+compiles.
 """
 
 from __future__ import annotations
@@ -52,9 +59,9 @@ def _kernel(g_ref, m_ref, c_ref, out_g_ref, out_c_ref):
     g = g_ref[...]                       # (N, bd) float
     m = m_ref[...]                       # (N, bd) mask (same dtype as g)
     c = c_ref[...]
-    count = jnp.sum(m, axis=0)           # (bd,)
-    fresh = jnp.sum(g * m, axis=0) / jnp.maximum(count, 1.0)
-    stale = jnp.mean(c, axis=0)
+    count = jnp.sum(m, axis=0, keepdims=True)          # (1, bd)
+    fresh = jnp.sum(g * m, axis=0, keepdims=True) / jnp.maximum(count, 1.0)
+    stale = jnp.mean(c, axis=0, keepdims=True)
     out_g_ref[...] = jnp.where(count > 0, fresh, stale)
     out_c_ref[...] = jnp.where(m > 0, g, c)
 
@@ -94,16 +101,16 @@ def _region_aggregate(grads, masks, memory, *, block_d: int,
             pl.BlockSpec((N, bd), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((bd,), lambda i: (i,)),
+            pl.BlockSpec((1, bd), lambda i: (0, i)),
             pl.BlockSpec((N, bd), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Dp,), dt),
+            jax.ShapeDtypeStruct((1, Dp), dt),
             jax.ShapeDtypeStruct((N, Dp), dt),
         ],
         interpret=interpret,
     )(grads, m, memory)
-    return out_g[:D], out_c[:, :D]
+    return out_g[0, :D], out_c[:, :D]
 
 
 def _fused_kernel(x_ref, h_ref, g_ref, m_ref, c_ref, out_x_ref, out_c_ref,
@@ -111,9 +118,9 @@ def _fused_kernel(x_ref, h_ref, g_ref, m_ref, c_ref, out_x_ref, out_c_ref,
     g = g_ref[...]
     m = m_ref[...]
     c = c_ref[...]
-    count = jnp.sum(m, axis=0)
-    fresh = jnp.sum(g * m, axis=0) / jnp.maximum(count, 1.0)
-    stale = jnp.mean(c, axis=0)
+    count = jnp.sum(m, axis=0, keepdims=True)          # (1, bd)
+    fresh = jnp.sum(g * m, axis=0, keepdims=True) / jnp.maximum(count, 1.0)
+    stale = jnp.mean(c, axis=0, keepdims=True)
     gbar = jnp.where(count > 0, fresh, stale)
     h_mu = jnp.maximum(h_ref[...], mu)   # diagonal [·]_μ projection
     out_x_ref[...] = x_ref[...] - lr * gbar / h_mu
@@ -154,20 +161,20 @@ def _ranl_update(params, hdiag, grads, masks, memory, *, mu: float,
         functools.partial(_fused_kernel, mu=mu, lr=lr),
         grid=(Dp // bd,),
         in_specs=[
-            pl.BlockSpec((bd,), lambda i: (i,)),
-            pl.BlockSpec((bd,), lambda i: (i,)),
+            pl.BlockSpec((1, bd), lambda i: (0, i)),
+            pl.BlockSpec((1, bd), lambda i: (0, i)),
             pl.BlockSpec((N, bd), lambda i: (0, i)),
             pl.BlockSpec((N, bd), lambda i: (0, i)),
             pl.BlockSpec((N, bd), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((bd,), lambda i: (i,)),
+            pl.BlockSpec((1, bd), lambda i: (0, i)),
             pl.BlockSpec((N, bd), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Dp,), dt),
+            jax.ShapeDtypeStruct((1, Dp), dt),
             jax.ShapeDtypeStruct((N, Dp), dt),
         ],
         interpret=interpret,
-    )(params, hdiag, grads, m, memory)
-    return out_x[:D], out_c[:, :D]
+    )(params[None, :], hdiag[None, :], grads, m, memory)
+    return out_x[0, :D], out_c[:, :D]

@@ -30,14 +30,13 @@ import traceback
 
 
 def _mesh_by_name(name: str):
-    import jax
-    from .mesh import make_production_mesh
+    from .mesh import make_mesh, make_production_mesh
     if name == "pod1":
         return make_production_mesh(multi_pod=False)
     if name == "pod2":
         return make_production_mesh(multi_pod=True)
     if name.startswith("tiny"):        # tiny8 -> (2,4); tiny2x4 etc.
-        return jax.make_mesh((2, 4), ("data", "model"))
+        return make_mesh((2, 4), ("data", "model"))
     raise ValueError(name)
 
 

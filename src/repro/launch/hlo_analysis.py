@@ -384,10 +384,8 @@ def summarize_collectives(records):
 def cost_raw_summary(compiled) -> dict:
     """``compiled.cost_analysis()`` -> the raw FLOPs/bytes dict the
     dry-run records and the obs journal header surfaces (scan bodies
-    counted once; tolerant of older jax returning ``[dict]``)."""
+    counted once)."""
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):     # older jax returns [dict]
-        ca = ca[0] if ca else {}
     return {k: float(v) for k, v in ca.items()
             if k in ("flops", "bytes accessed", "transcendentals")}
 

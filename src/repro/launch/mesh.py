@@ -9,6 +9,19 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with every axis Auto.
+
+    ``jax.make_mesh`` defaults to Explicit axes, under which
+    ``with_sharding_constraint`` (``models.sharding.shard_hint``) and the
+    placement-only sharding of the batch engine refuse the mesh axes.
+    Every mesh this package builds goes through here.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_engine_mesh(data_shards: int, model_shards: int = 1,
@@ -44,12 +57,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1-device mesh with the production axis names (tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def data_shards(mesh) -> int:

@@ -23,6 +23,7 @@ the devices.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 from contextlib import nullcontext
@@ -38,6 +39,7 @@ from ..obs import Journal, Tracer, make_header
 from ..optim import (AdamWConfig, RanlLLMConfig, adamw_init, adamw_step,
                      init_state, train_step)
 from ..checkpoint import save
+from .cache import use_compile_cache
 
 
 def build_loss(cfg, q_chunk=1024, kv_chunk=1024, remat=True):
@@ -52,6 +54,12 @@ def run(argv=None):
     ap.add_argument("--arch", default="phi4-mini-3.8b")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced config (CPU-runnable)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the registered config to this many layers "
+                         "(widths unchanged; 0 = published depth)")
+    ap.add_argument("--vocab", type=int, default=0,
+                    help="cut the registered config to this vocabulary "
+                         "size (widths unchanged; 0 = published vocab)")
     ap.add_argument("--optimizer", default="ranl",
                     choices=["ranl", "adamw"])
     ap.add_argument("--steps", type=int, default=10)
@@ -125,6 +133,7 @@ def run(argv=None):
                          "PATH (open in Perfetto); spans also land in "
                          "the --journal when both are set")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.dump_hlo and args.optimizer != "ranl":
         raise SystemExit("--dump-hlo reports the RANL train step; rerun "
                          "with --optimizer ranl (the baseline optimizers "
@@ -143,7 +152,23 @@ def run(argv=None):
 
     cfg = get_config(args.arch)
     if args.smoke:
+        if args.layers or args.vocab:
+            raise SystemExit("--layers/--vocab cut the published config; "
+                             "--smoke already replaces it")
         cfg = smoke_variant(cfg)
+    elif args.layers or args.vocab:
+        if args.layers < 0 or args.vocab < 0:
+            raise SystemExit("--layers/--vocab must be positive")
+        cut = {}
+        if args.layers:
+            cut["num_layers"] = args.layers
+        if args.vocab:
+            cut["vocab_size"] = args.vocab
+        cfg = dataclasses.replace(cfg, **cut)
+        print(f"config cut: {args.arch} layers={cfg.num_layers} "
+              f"vocab={cfg.vocab_size} (d_model={cfg.d_model} "
+              f"d_ff={cfg.d_ff} heads={cfg.num_heads}/{cfg.num_kv_heads} "
+              f"x{cfg.resolved_head_dim} as published)")
     mesh = None
     if args.pods < 1:
         raise SystemExit(f"--pods {args.pods} must be >= 1")
@@ -164,7 +189,8 @@ def run(argv=None):
                 f"but jax sees {ndev}; set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count="
                 f"{args.data_shards} to emulate them")
-        mesh = jax.make_mesh((args.data_shards,), ("data",))
+        from .mesh import make_mesh
+        mesh = make_mesh((args.data_shards,), ("data",))
         print(f"mesh: {args.data_shards}-way ('data',) over "
               f"{jax.devices()[0].platform}")
     key = jax.random.PRNGKey(args.seed)

@@ -38,10 +38,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import repro
+from repro.launch.mesh import make_mesh
 from repro.analysis import (
     CollectiveBudget,
     CommContract,
@@ -129,7 +130,7 @@ def test_collective_axes_joint_multi_axis_reduction():
 def test_single_replica_mesh_contract_regression():
     prob = _problem()
     opts = repro.RanlOptions(num_rounds=3, num_regions=4)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     low = repro.lower(prob, KEY, engine="sharded", mesh=mesh, options=opts)
     comm, mem = engine_contract("sharded", opts, dim=32, num_workers=4,
                                 mesh_shape=(1,), mesh_axes=("data",))
@@ -150,7 +151,7 @@ def test_single_replica_mesh_contract_regression():
 # --------------------------------------------------------------------------
 
 def _toy_loop(n_psums: int):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     def body(c, _):
         g = jax.lax.psum(c, "data")
@@ -302,7 +303,7 @@ def test_cost_analysis_pinned_to_jaxpr_inventory():
     jscan = audit_jaxpr(repro.trace(prob, KEY, engine="scan",
                                     options=opts))
     assert jscan.signature() == {} and jscan.ok
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jsh = audit_jaxpr(repro.trace(prob, KEY, engine="sharded",
                                   options=opts, mesh=mesh))
     n_jaxpr = jsh.reduce_count(in_loop=True)
@@ -310,8 +311,6 @@ def test_cost_analysis_pinned_to_jaxpr_inventory():
     compiled = repro.lower(prob, KEY, engine="sharded", options=opts,
                            mesh=mesh).compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):     # older jax returns [dict]
-        ca = ca[0] if ca else {}
     assert float(ca.get("flops", 0.0)) > 0.0
     recs = collect_collectives(compiled.as_text(),
                                default_trip=opts.num_rounds)
@@ -503,13 +502,14 @@ import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import dataclasses, json
 import jax
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config, smoke_variant, INPUT_SHAPES
 from repro.launch.dryrun import cost_graphs
 from repro.launch.steps import make_bundle
 from repro.models.sharding import use_mesh
 from repro.analysis import audit_jaxpr
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 cfg = dataclasses.replace(smoke_variant(get_config('hymba-1.5b')),
                           num_layers=4)
 shape = dataclasses.replace(INPUT_SHAPES['train_4k'],

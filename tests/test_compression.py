@@ -417,6 +417,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_mesh
 assert jax.device_count() == 8, jax.devices()
 KEY = jax.random.PRNGKey(0)
 """
@@ -438,7 +439,7 @@ D, T = 512, 7
 prob = make_quadratic(KEY, num_workers=8, dim=D, kappa=10.0,
                       coupling=0.0, num_regions=8)
 pol = PolicyConfig(keep_prob=0.5, tau_star=1, heterogeneous=False)
-mesh = jax.make_mesh((8,), ('data',))
+mesh = make_mesh((8,), ('data',))
 
 out = {}
 for comp, tag in ((None, 'none'), ('int8', 'int8')):

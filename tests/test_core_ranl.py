@@ -7,6 +7,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 import repro
+from repro.launch.mesh import make_mesh
 from repro.core import (PolicyConfig, blocked_cho_solve, blocked_cholesky,
                         ensure_coverage, expand_mask,
                         contiguous_regions, fisher_diag, make_quadratic,
@@ -138,7 +139,7 @@ def test_project_psd_sharded_single_device_matches_oracles():
     eigh oracle to NS tolerance.  (The non-dividing-dim guard needs a
     multi-device model axis and is exercised in tests/test_multidevice.py
     alongside the engine's divisibility guards.)"""
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     a = _straddling_matrix(24, 0.6, 4)
     sh = project_psd_sharded(a, 0.6, mesh=mesh)
     assert float(jnp.abs(sh - project_psd_ns(a, 0.6)).max()) <= 1e-6

@@ -22,6 +22,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 import repro
+from repro.launch.mesh import make_mesh
 from repro.core import (PolicyConfig, ensure_coverage, make_quadratic,
                         sample_masks)
 from repro.hetero import (CostModel, PolicyController,
@@ -346,7 +347,7 @@ def test_closed_loop_sharded_engines_single_device_parity():
     kw = dict(num_rounds=10, num_regions=6, controller=ctrl,
               cost=scen.cost)
     ref = repro.run(prob, KEY, **kw)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = repro.run(prob, KEY, engine="sharded", mesh=mesh, **kw)
     assert np.abs(np.asarray(sh.xs) - np.asarray(ref.xs)).max() <= 1e-6
     np.testing.assert_array_equal(np.asarray(sh.comm_floats),
@@ -359,7 +360,7 @@ def test_closed_loop_sharded_engines_single_device_parity():
     np.testing.assert_array_equal(np.asarray(ov.xs), np.asarray(sh.xs))
     np.testing.assert_array_equal(np.asarray(ov.round_time),
                                   np.asarray(sh.round_time))
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh2 = make_mesh((1, 1), ("data", "model"))
     for curv in ("dense", "diag"):
         ref2 = repro.run(prob, KEY, curvature=curv,
                         use_kernel=(curv == "diag"),
@@ -447,6 +448,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_mesh
 assert jax.device_count() == 8, jax.devices()
 KEY = jax.random.PRNGKey(0)
 import repro
@@ -464,7 +466,7 @@ for scen_spec in ('pareto-stragglers', 'churn:period=3,cohorts=4,alpha=1.2'):
     kw = dict(num_rounds=12, num_regions=6, controller=ctrl, cost=scen.cost)
     ref = repro.run(prob, KEY, **kw)
     for ndev in (1, 8):
-        mesh = jax.make_mesh((ndev,), ('data',))
+        mesh = make_mesh((ndev,), ('data',))
         for ov in (False, True):
             sh = repro.run(prob, KEY, engine="sharded", mesh=mesh, overlap=ov, **kw)
             out["parity"]["%s_%d_%s" % (scen.name, ndev, ov)] = {
@@ -484,7 +486,7 @@ for scen_spec in ('pareto-stragglers', 'churn:period=3,cohorts=4,alpha=1.2'):
 D, T = 512, 7
 prob_h = make_quadratic(KEY, num_workers=N, dim=D, kappa=10.0,
                         coupling=0.0, num_regions=8)
-mesh8 = jax.make_mesh((8,), ('data',))
+mesh8 = make_mesh((8,), ('data',))
 scen = make_scenario('pareto-stragglers', jax.random.PRNGKey(3), N)
 out["hlo"] = {}
 for leg, ov in (("seq", False), ("overlap", True)):

@@ -199,6 +199,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_mesh
 assert jax.device_count() == 8, jax.devices()
 KEY = jax.random.PRNGKey(0)
 """
@@ -234,7 +235,7 @@ prob = make_quadratic(KEY, num_workers=8, dim=D, kappa=80.0,
 pol = PolicyConfig(keep_prob=0.5, tau_star=1, heterogeneous=False)
 opts = repro.RanlOptions(num_rounds=T, num_regions=6, policy=pol,
                          hierarchy=f"pods=2,period={PERIOD}")
-mesh1d = jax.make_mesh((2, 4), ('pod', 'data'))
+mesh1d = make_mesh((2, 4), ('pod', 'data'))
 mesh2d = make_engine_mesh(2, 2, pods=2)
 assert mesh2d.axis_names == ('pod', 'data', 'model')
 

@@ -39,6 +39,7 @@ import numpy as np
 import pytest
 
 import repro
+from repro.launch.mesh import make_mesh
 from repro.core import PolicyConfig, make_quadratic
 
 KEY = jax.random.PRNGKey(0)
@@ -62,6 +63,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_mesh
 assert jax.device_count() == 8, jax.devices()
 KEY = jax.random.PRNGKey(0)
 """
@@ -81,7 +83,7 @@ def test_sharded_single_device_mesh_matches_scan():
                           coupling=0.0, num_regions=6, grad_noise=0.1,
                           hess_noise=0.1)
     pol = PolicyConfig(keep_prob=0.5, tau_star=1, heterogeneous=False)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = repro.run(prob, KEY, engine="sharded", mesh=mesh, num_rounds=8,
                           num_regions=6, policy=pol)
     ref = repro.run(prob, KEY, num_rounds=8, num_regions=6, policy=pol)
@@ -105,7 +107,7 @@ def test_sharded_single_device_mesh_matches_scan():
 def test_sharded_mesh_validation_errors():
     prob = make_quadratic(KEY, num_workers=4, dim=16, kappa=10.0,
                           coupling=0.0, num_regions=4)
-    no_data = jax.make_mesh((1,), ("model",))
+    no_data = make_mesh((1,), ("model",))
     with pytest.raises(ValueError, match="data"):
         repro.run(prob, KEY, engine="sharded", mesh=no_data, num_rounds=2)
     with pytest.raises(ValueError, match="data"):
@@ -125,7 +127,7 @@ def test_sharded2d_single_device_mesh_matches_scan():
     prob = make_quadratic(KEY, num_workers=8, dim=48, kappa=80.0,
                           coupling=0.0, num_regions=6, grad_noise=0.1,
                           hess_noise=0.1)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for pol, curv in ((PolicyConfig(keep_prob=0.5, tau_star=1,
                                     heterogeneous=False), "dense"),
                       (PolicyConfig(name="staleness", stale_period=3),
@@ -157,10 +159,10 @@ def test_sharded2d_mesh_validation_errors():
     prob = make_quadratic(KEY, num_workers=4, dim=16, kappa=10.0,
                           coupling=0.0, num_regions=4)
     with pytest.raises(ValueError, match="model"):
-        repro.run(prob, KEY, engine="sharded2d", mesh=jax.make_mesh((1,), ("data",)),
+        repro.run(prob, KEY, engine="sharded2d", mesh=make_mesh((1,), ("data",)),
                            num_rounds=2)
     with pytest.raises(ValueError, match="data"):
-        repro.run(prob, KEY, engine="sharded2d", mesh=jax.make_mesh((1,), ("model",)),
+        repro.run(prob, KEY, engine="sharded2d", mesh=make_mesh((1,), ("model",)),
                            num_rounds=2)
 
 
@@ -182,7 +184,7 @@ pol = PolicyConfig(keep_prob=0.5, tau_star=1, heterogeneous=False)
 ref = repro.run(prob, KEY, num_rounds=12, num_regions=6, policy=pol)
 out = {"parity": {}}
 for ndev in (1, 2, 8):
-    mesh = jax.make_mesh((ndev,), ('data',))
+    mesh = make_mesh((ndev,), ('data',))
     sh = repro.run(prob, KEY, engine="sharded", mesh=mesh, num_rounds=12,
                           num_regions=6, policy=pol)
     out["parity"][str(ndev)] = {
@@ -195,7 +197,7 @@ for ndev in (1, 2, 8):
         "tau_eq": bool(sh.tau_star == ref.tau_star),
     }
 
-mesh8 = jax.make_mesh((8,), ('data',))
+mesh8 = make_mesh((8,), ('data',))
 sh_d = repro.run(prob, KEY, engine="sharded", mesh=mesh8, num_rounds=12,
                         num_regions=6, policy=pol, curvature='diag')
 ref_d = repro.run(prob, KEY, num_rounds=12, num_regions=6, policy=pol,
@@ -253,7 +255,7 @@ from repro.core import PolicyConfig, make_quadratic
 prob = make_quadratic(KEY, num_workers=8, dim=48, kappa=80.0, coupling=0.0,
                       num_regions=6, grad_noise=0.1, hess_noise=0.1)
 pol = PolicyConfig(keep_prob=0.5, tau_star=1, heterogeneous=False)
-mesh8 = jax.make_mesh((8,), ('data',))
+mesh8 = make_mesh((8,), ('data',))
 out = {}
 kw = dict(num_rounds=12, num_regions=6, policy=pol)
 seq = repro.run(prob, KEY, engine="sharded", mesh=mesh8, **kw)
@@ -443,7 +445,7 @@ keys = jax.random.split(KEY, 8)
 ref = repro.run(prob, keys, engine="batch", num_rounds=10, num_regions=4, policy=pol)
 out = {}
 for ndev in (1, 2, 8):
-    mesh = jax.make_mesh((ndev,), ('data',))
+    mesh = make_mesh((ndev,), ('data',))
     bat = repro.run(prob, keys, engine="batch", num_rounds=10, num_regions=4,
                          policy=pol, mesh=mesh)
     out[str(ndev)] = {
@@ -457,7 +459,7 @@ for ndev in (1, 2, 8):
     }
 try:
     repro.run(prob, jax.random.split(KEY, 6), engine="batch", num_rounds=2,
-                   mesh=jax.make_mesh((8,), ('data',)))
+                   mesh=make_mesh((8,), ('data',)))
     out["divisibility_raises"] = False
 except ValueError:
     out["divisibility_raises"] = True
@@ -494,7 +496,7 @@ ref = jax.jit(partial(train_step, loss_fn=loss_fn, cfg=rcfg))
 p1, s1, m1 = ref(params, state, batch, KEY)
 out = {"parity": {}}
 for ndev in (1, 2, 8):
-    mesh = jax.make_mesh((ndev,), ('data',))
+    mesh = make_mesh((ndev,), ('data',))
     sh = jax.jit(partial(train_step, loss_fn=loss_fn, cfg=rcfg, mesh=mesh))
     p2, s2, m2 = sh(params, state, batch, KEY)
     perr = prel = 0.0
@@ -517,7 +519,7 @@ for ndev in (1, 2, 8):
 # stated as an aggregate-bytes contract (the window applies to the SUM
 # of every matching all-reduce, not per-collective)
 from repro.analysis import CollectiveBudget, CommContract, verify_contract
-mesh8 = jax.make_mesh((8,), ('data',))
+mesh8 = make_mesh((8,), ('data',))
 sh8 = jax.jit(partial(train_step, loss_fn=loss_fn, cfg=rcfg, mesh=mesh8))
 grad_bytes = sum(l.size * 4 for l in jax.tree.leaves(params))
 comm = CommContract(

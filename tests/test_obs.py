@@ -34,6 +34,7 @@ import numpy as np
 import pytest
 
 import repro
+from repro.launch.mesh import make_mesh
 from repro.core import PolicyConfig, make_quadratic
 from repro.obs import (Journal, MetricsRegistry, Tracer, check_byte_drift,
                        hlo_header, make_header, read_journal,
@@ -182,12 +183,12 @@ def test_bit_exact_batch_seeds_header():
 
 
 def test_bit_exact_sharded_one_device():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     _assert_bit_exact("sharded", _opts(), KEY, mesh=mesh)
 
 
 def test_bit_exact_sharded2d_one_device():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     _assert_bit_exact("sharded2d", _opts(), KEY, mesh=mesh)
 
 
@@ -302,7 +303,7 @@ def test_run_records_execute_span_into_journal():
 
 
 def test_lower_records_span():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with tracing() as tr:
         repro.lower(_problem(), KEY, engine="sharded", options=_opts(),
                     mesh=mesh)
@@ -440,7 +441,7 @@ def test_hlo_header_byte_totals(tmp_path):
 
 
 def test_hlo_header_counts_in_loop_collectives():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     txt = repro.lower(_problem(), KEY, engine="sharded", options=_opts(),
                       mesh=mesh).compile().as_text()
     from repro.launch.hlo_analysis import module_report

@@ -344,6 +344,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_mesh
 assert jax.device_count() == 8, jax.devices()
 import repro
 from repro.core import PolicyConfig, make_quadratic
@@ -355,7 +356,7 @@ D, T = 512, 7
 prob = make_quadratic(KEY, num_workers=16, dim=D, kappa=80.0,
                       coupling=0.0, num_regions=8)
 scen = make_scenario("pareto-stragglers", jax.random.PRNGKey(3), 16)
-mesh = jax.make_mesh((8,), ('data',))
+mesh = make_mesh((8,), ('data',))
 pol = PolicyConfig(keep_prob=0.5, tau_star=1, heterogeneous=True)
 out = {}
 for overlap in (False, True):

@@ -11,6 +11,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ALL_ARCHS, get_config, smoke_variant
+from repro.launch.mesh import make_mesh
 from repro.launch.shard import (batch_pspecs, cache_pspecs, params_pspecs,
                                 ranl_state_pspecs, trim_tree, worker_prefix)
 
@@ -53,7 +54,7 @@ def test_worker_prefix_strips_batch_axes():
 
 
 def test_trim_tree_drops_missing_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     t = trim_tree({"a": P(("pod", "data"), "model")}, mesh)
     assert t["a"] == P(("data",), "model")
 
@@ -78,8 +79,9 @@ import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import jax, dataclasses, json
 from repro.configs import get_config, smoke_variant, INPUT_SHAPES
+from repro.launch.mesh import make_mesh
 from repro.launch.dryrun import lower_and_compile
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 cfg = dataclasses.replace(smoke_variant(get_config('hymba-1.5b')),
                           num_layers=4)
 shape = dataclasses.replace(INPUT_SHAPES['train_4k'],
@@ -145,10 +147,10 @@ def test_named_sharding_trims_missing_mesh_axes():
     meshes: axes the active mesh lacks are dropped (the single-pod /
     single-model degenerate layouts)."""
     from repro.models.sharding import named_sharding
-    mesh_dm = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_dm = make_mesh((1, 1), ("data", "model"))
     s = named_sharding(mesh_dm, "batch", "embed")
     assert s.spec == P(("data",), "model")
-    mesh_d = jax.make_mesh((1,), ("data",))
+    mesh_d = make_mesh((1,), ("data",))
     s = named_sharding(mesh_d, "batch", "embed")
     assert s.spec == P(("data",), None)
     assert named_sharding(mesh_d, "pods").spec == P(None)
@@ -159,7 +161,7 @@ def test_shard_hint_logical_spec():
     x = jnp.ones((4, 8))
     # mesh-agnostic: a no-op when no mesh is installed
     assert shard_hint(x, ("batch", "embed")) is x
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with use_mesh(mesh):
         y = jax.jit(lambda a: shard_hint(a, ("batch", "embed")))(x)
     # on the degenerate 1x1 mesh the constraint canonicalizes to fully

@@ -1,0 +1,64 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e.
+
+No chip is needed: the TPU compiler compiles for a topology that is
+described, not attached.  Interpret mode (what every other kernel test
+runs) cannot see the chip's tiling rules; these compiles can.  The
+topology is described inside a fixture, never at import, so that every
+pytest-xdist worker collects the same tests and only the worker that
+runs this file loads the TPU library.
+"""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.region_aggregate import ranl_update, region_aggregate
+
+N = 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to a persistent cache but
+    # cannot be read back without one: keep the cache off around these
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(one_chip, d):
+    def s(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return {"vec": s(d), "tile": s(N, d), "mask": s(N, d, dtype=jnp.bool_)}
+
+
+@pytest.mark.parametrize("kernel", ["ranl_update", "region_aggregate"])
+@pytest.mark.parametrize("d", [4096, 3000, 1 << 20])
+def test_kernel_compiles_for_v5e(one_chip, kernel, d):
+    sh = _shapes(one_chip, d)
+    if kernel == "ranl_update":
+        fn = partial(ranl_update, mu=1e-3, lr=1.0, interpret=False)
+        args = (sh["vec"], sh["vec"], sh["tile"], sh["mask"], sh["tile"])
+    else:
+        fn = partial(region_aggregate, interpret=False)
+        args = (sh["tile"], sh["mask"], sh["tile"])
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
