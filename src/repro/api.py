@@ -57,6 +57,7 @@ from .core.ranl import (
     _run_sharded2d,
     trace_ranl,
 )
+from .obs.trace import span
 
 ENGINES = ("scan", "batch", "sharded", "sharded2d", "reference")
 _MESH_REQUIRED = ("sharded", "sharded2d")
@@ -144,8 +145,7 @@ def run(problem, key, *, engine: str = "scan",
     """
     opts, controller = _resolve(engine, options, mesh, controller,
                                 overrides)
-    from .obs.trace import span
-    with span("execute", engine=engine):
+    with span("ranl.run", engine=engine):
         if engine == "scan":
             result = _run_scan(problem, key, opts, controller=controller,
                                cost=cost)
@@ -194,8 +194,7 @@ def lower(problem, key, *, engine: str = "sharded",
                          f"repro.lower supports {_MESH_REQUIRED}")
     opts, controller = _resolve(engine, options, mesh, controller,
                                 overrides)
-    from .obs.trace import span
-    with span("lower", engine=engine):
+    with span("ranl.lower", engine=engine):
         if engine == "sharded":
             return _lower_sharded(problem, key, opts, mesh=mesh,
                                   axis_name=axis_name, pod_axis=pod_axis,

@@ -109,6 +109,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.trace import span
 from .aggregation import late_fold_updates, quorum_aggregate, \
     server_aggregate
 from .compression import CompressionSpec, compressed_quorum_aggregate, \
@@ -176,55 +177,66 @@ def _init_phase(problem, k_init, *, mu: float, lr: float, curvature: str,
     matmul-only Newton–Schulz form — the single-device oracle of the
     dimension-sharded init).
     """
-    N, d = problem.num_workers, problem.dim
-    worker_ids = jnp.arange(N)
-    grad_at = jax.vmap(problem.worker_grad, in_axes=(0, None, 0))
+    with span("ranl.init"):
+        N, d = problem.num_workers, problem.dim
+        worker_ids = jnp.arange(N)
+        grad_at = jax.vmap(problem.worker_grad, in_axes=(0, None, 0))
 
-    x0 = jnp.zeros(d)
-    hkeys = jax.random.split(jax.random.fold_in(k_init, 0), N)
-    gkeys = jax.random.split(jax.random.fold_in(k_init, 1), N)
-    g0 = grad_at(worker_ids, x0, gkeys)          # (N, d)
+        x0 = jnp.zeros(d)
+        hkeys = jax.random.split(jax.random.fold_in(k_init, 0), N)
+        gkeys = jax.random.split(jax.random.fold_in(k_init, 1), N)
+        with span("ranl.init.grad"):
+            g0 = grad_at(worker_ids, x0, gkeys)          # (N, d)
 
-    if curvature == "dense" and hessian_rank is not None:
-        # compressed init exchange: project worker 0's Hessian once, fold
-        # only the top-r eigenpairs of every other worker's curvature via
-        # Cholesky rank-1 updates — no mean-Hessian re-projection (see
-        # compression.lowrank_hmu_factor for the exactness regime)
-        cho_c, cho_lower = lowrank_hmu_factor(
-            problem, x0, hkeys, mu, rank=hessian_rank), True
-        hdiag = None
-        step0 = jax.scipy.linalg.cho_solve((cho_c, cho_lower),
-                                           g0.mean(axis=0))
-    elif curvature == "dense":
-        # O(d²)-peak shared fold (see running_mean_hessian: the eager
-        # left-to-right order is what keeps reference parity bit-tight;
-        # the sharded2d dense init, whose oracle tolerance is 1e-5, uses
-        # lax.scan for its panel accumulation instead).
-        H = running_mean_hessian(problem, x0, hkeys)
-        if projection == "ns":
-            h_mu = project_psd_ns(H, mu, num_iters=ns_iters)
+        if curvature == "dense" and hessian_rank is not None:
+            # compressed init exchange: project worker 0's Hessian once,
+            # fold only the top-r eigenpairs of every other worker's
+            # curvature via Cholesky rank-1 updates — no mean-Hessian
+            # re-projection (see compression.lowrank_hmu_factor for the
+            # exactness regime)
+            with span("ranl.init.hessian"):
+                cho_c, cho_lower = lowrank_hmu_factor(
+                    problem, x0, hkeys, mu, rank=hessian_rank), True
+            hdiag = None
+            with span("ranl.init.factor"):
+                step0 = jax.scipy.linalg.cho_solve((cho_c, cho_lower),
+                                                   g0.mean(axis=0))
+        elif curvature == "dense":
+            # O(d²)-peak shared fold (see running_mean_hessian: the eager
+            # left-to-right order is what keeps reference parity
+            # bit-tight; the sharded2d dense init, whose oracle tolerance
+            # is 1e-5, uses lax.scan for its panel accumulation instead).
+            with span("ranl.init.hessian"):
+                H = running_mean_hessian(problem, x0, hkeys)
+            with span("ranl.init.project"):
+                if projection == "ns":
+                    h_mu = project_psd_ns(H, mu, num_iters=ns_iters)
+                else:
+                    h_mu = project_psd(H, mu)
+            with span("ranl.init.factor"):
+                cho_c, cho_lower = jax.scipy.linalg.cho_factor(h_mu)
+                step0 = jax.scipy.linalg.cho_solve((cho_c, cho_lower),
+                                                   g0.mean(axis=0))
+            hdiag = None
+        elif curvature == "diag":
+            # Scalable path: Hutchinson diagonal of the mean worker Hessian
+            # at x⁰ (Rademacher probes, HVPs through the gradient oracle);
+            # the per-round step then only needs max(h, μ) — the diagonal
+            # specialization of [·]_μ.
+            def mean_grad(xx):
+                return grad_at(worker_ids, xx, gkeys).mean(axis=0)
+
+            with span("ranl.init.hessian"):
+                hdiag = hutchinson_diag(mean_grad, x0,
+                                        jax.random.fold_in(k_init, 2),
+                                        num_samples=hutch_samples)
+            cho_c, cho_lower = None, False
+            with span("ranl.init.factor"):
+                step0 = g0.mean(axis=0) / project_diag(hdiag, mu)
         else:
-            h_mu = project_psd(H, mu)
-        cho_c, cho_lower = jax.scipy.linalg.cho_factor(h_mu)
-        hdiag = None
-        step0 = jax.scipy.linalg.cho_solve((cho_c, cho_lower),
-                                           g0.mean(axis=0))
-    elif curvature == "diag":
-        # Scalable path: Hutchinson diagonal of the mean worker Hessian at
-        # x⁰ (Rademacher probes, HVPs through the gradient oracle); the
-        # per-round step then only needs max(h, μ) — the diagonal
-        # specialization of [·]_μ.
-        def mean_grad(xx):
-            return grad_at(worker_ids, xx, gkeys).mean(axis=0)
+            raise ValueError(f"unknown curvature {curvature!r}")
 
-        hdiag = hutchinson_diag(mean_grad, x0, jax.random.fold_in(k_init, 2),
-                                num_samples=hutch_samples)
-        cho_c, cho_lower = None, False
-        step0 = g0.mean(axis=0) / project_diag(hdiag, mu)
-    else:
-        raise ValueError(f"unknown curvature {curvature!r}")
-
-    x1 = x0 - lr * step0
+        x1 = x0 - lr * step0
     return x1, g0, cho_c, cho_lower, hdiag
 
 
@@ -1138,19 +1150,28 @@ def _run_sharded(problem, key, opts: RanlOptions, *, mesh,
                                  axis_name=axis_name,
                                  controller=controller, cost=cost,
                                  pod_axis=pod_axis)
-    (xs, cov, comm, tau, tau_cov, times, stale, cbytes,
-     pbytes) = _sharded_jit(*args, **static)
-    xs_pods = None
-    if static["hspec"] is not None:
-        xs_pods, xs = xs, xs.mean(axis=1)
-    dist = jnp.sum((xs - problem.x_star[None, :]) ** 2, axis=1)
-    losses = jax.vmap(problem.loss)(xs)
-    return _subsampled(RanlResult(
-        xs=xs, dist_sq=dist, losses=losses, coverage=cov,
-        comm_floats=comm, tau_star=int(tau), tau_covered=int(tau_cov),
-        round_time=times, max_stale=stale, comm_bytes=cbytes,
-        pod_bytes=pbytes, xs_pods=xs_pods),
-        opts.record_every)
+    with span("ranl.rounds"):
+        out = _sharded_jit(*args, **static)
+    return _sharded_result(problem, opts, static["hspec"], out)
+
+
+def _sharded_result(problem, opts: RanlOptions, hspec, out):
+    """A sharded engine's outputs as a RanlResult: the pod mean of a
+    hierarchical run, the distance and loss traces, and the host reads
+    of the coverage pair, where the host waits for the round loop."""
+    with span("ranl.result"):
+        (xs, cov, comm, tau, tau_cov, times, stale, cbytes, pbytes) = out
+        xs_pods = None
+        if hspec is not None:
+            xs_pods, xs = xs, xs.mean(axis=1)
+        dist = jnp.sum((xs - problem.x_star[None, :]) ** 2, axis=1)
+        losses = jax.vmap(problem.loss)(xs)
+        return _subsampled(RanlResult(
+            xs=xs, dist_sq=dist, losses=losses, coverage=cov,
+            comm_floats=comm, tau_star=int(tau), tau_covered=int(tau_cov),
+            round_time=times, max_stale=stale, comm_bytes=cbytes,
+            pod_bytes=pbytes, xs_pods=xs_pods),
+            opts.record_every)
 
 
 def _lower_sharded(problem, key, opts: RanlOptions, *, mesh,
@@ -1869,19 +1890,9 @@ def _run_sharded2d(problem, key, opts: RanlOptions, *, mesh,
         problem, key, opts, mesh=mesh, data_axis=data_axis,
         model_axis=model_axis, controller=controller, cost=cost,
         pod_axis=pod_axis)
-    (xs, cov, comm, tau, tau_cov, times, stale, cbytes,
-     pbytes) = engine(*args, **static)
-    xs_pods = None
-    if static["hspec"] is not None:
-        xs_pods, xs = xs, xs.mean(axis=1)
-    dist = jnp.sum((xs - problem.x_star[None, :]) ** 2, axis=1)
-    losses = jax.vmap(problem.loss)(xs)
-    return _subsampled(RanlResult(
-        xs=xs, dist_sq=dist, losses=losses, coverage=cov,
-        comm_floats=comm, tau_star=int(tau), tau_covered=int(tau_cov),
-        round_time=times, max_stale=stale, comm_bytes=cbytes,
-        pod_bytes=pbytes, xs_pods=xs_pods),
-        opts.record_every)
+    with span("ranl.rounds"):
+        out = engine(*args, **static)
+    return _sharded_result(problem, opts, static["hspec"], out)
 
 
 def _lower_sharded2d(problem, key, opts: RanlOptions, *, mesh,
@@ -1992,17 +2003,19 @@ def _run_scan(problem, key, opts: RanlOptions, *, controller=None,
     """
     args, static = _scan_args(problem, key, opts, controller=controller,
                               cost=cost)
-    (xs, dist, losses, cov, comm, tau, tau_cov, times, stale,
-     cbytes, pbytes) = _rounds_jit(*args, **static)
-    xs_pods = None
-    if static["hspec"] is not None:
-        xs_pods, xs = xs, xs.mean(axis=1)
-    return _subsampled(RanlResult(
-        xs=xs, dist_sq=dist, losses=losses, coverage=cov,
-        comm_floats=comm, tau_star=int(tau), tau_covered=int(tau_cov),
-        round_time=times, max_stale=stale, comm_bytes=cbytes,
-        pod_bytes=pbytes, xs_pods=xs_pods),
-        opts.record_every)
+    with span("ranl.rounds"):
+        (xs, dist, losses, cov, comm, tau, tau_cov, times, stale,
+         cbytes, pbytes) = _rounds_jit(*args, **static)
+    with span("ranl.result"):
+        xs_pods = None
+        if static["hspec"] is not None:
+            xs_pods, xs = xs, xs.mean(axis=1)
+        return _subsampled(RanlResult(
+            xs=xs, dist_sq=dist, losses=losses, coverage=cov,
+            comm_floats=comm, tau_star=int(tau), tau_covered=int(tau_cov),
+            round_time=times, max_stale=stale, comm_bytes=cbytes,
+            pod_bytes=pbytes, xs_pods=xs_pods),
+            opts.record_every)
 
 
 def _run_batch(problem, keys, opts: RanlOptions, *, mesh=None,

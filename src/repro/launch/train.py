@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from ..configs import get_config, smoke_variant
 from ..data import make_batch
 from ..models import init_model, lm_loss
-from ..obs import Journal, Tracer, make_header
+from ..obs import Journal, Tracer, make_header, span, tracing
 from ..optim import (AdamWConfig, RanlLLMConfig, adamw_init, adamw_step,
                      init_state, train_step)
 from ..checkpoint import save
@@ -128,11 +128,18 @@ def run(argv=None):
                          "step + summary — render it with "
                          "'python -m repro.obs.report PATH'")
     ap.add_argument("--trace", default="", metavar="PATH",
-                    help="span-trace the run (lower/compile/execute/"
-                         "checkpoint) and write Chrome-trace JSON to "
-                         "PATH (open in Perfetto); spans also land in "
-                         "the --journal when both are set")
+                    help="record the run's ranl.train.* spans (lower/"
+                         "compile/execute/checkpoint) and write "
+                         "Chrome-trace JSON to PATH (open in Perfetto); "
+                         "spans also land in the --journal when both are "
+                         "set")
     args = ap.parse_args(argv)
+    tracer = Tracer() if args.trace else None
+    with tracing(tracer) if tracer is not None else nullcontext():
+        return _train(args, tracer)
+
+
+def _train(args, tracer):
     use_compile_cache()
     if args.dump_hlo and args.optimizer != "ranl":
         raise SystemExit("--dump-hlo reports the RANL train step; rerun "
@@ -204,11 +211,6 @@ def run(argv=None):
 
     history = []
     journal = Journal(args.journal) if args.journal else None
-    tracer = Tracer() if args.trace else None
-
-    def tspan(name, **meta):
-        return (tracer.span(name, **meta) if tracer is not None
-                else nullcontext())
 
     if args.optimizer == "ranl":
         rcfg = RanlLLMConfig(num_workers=args.workers,
@@ -258,9 +260,9 @@ def run(argv=None):
         if args.dump_hlo:
             from .hlo_analysis import cost_raw_summary, module_report
             from ..obs import hlo_header
-            with tspan("lower"):
+            with span("ranl.train.lower"):
                 lowered = step_fn.lower(params, state, batch0, ko)
-            with tspan("compile"):
+            with span("ranl.train.compile"):
                 compiled = lowered.compile()
             txt = compiled.as_text()
             with open(args.dump_hlo, "w") as f:
@@ -310,14 +312,14 @@ def run(argv=None):
             if tracer is not None and exec_fn is None:
                 # AOT split so lowering/compile time is attributable
                 # (the jit path would fold both into the first execute)
-                with tracer.span("lower"):
+                with span("ranl.train.lower"):
                     low = step_fn.lower(params, state, batch, ko,
                                         masks=masks)
-                with tracer.span("compile"):
+                with span("ranl.train.compile"):
                     exec_fn = low.compile()
             fn = exec_fn if exec_fn is not None else step_fn
             t0 = time.perf_counter()
-            with tspan("execute", step=t):
+            with span("ranl.train.execute", step=t):
                 params, state, metrics = fn(params, state, batch, ko,
                                             masks=masks)
             sim_note = ""
@@ -369,7 +371,7 @@ def run(argv=None):
         for t in range(args.steps):
             batch = make_batch(cfg, jax.random.fold_in(kd, t + 1),
                                args.batch, args.seq, pattern=args.pattern)
-            with tspan("execute", step=t):
+            with span("ranl.train.execute", step=t):
                 params, state, loss = astep(params, state, batch)
             if (journal is not None or t % args.log_every == 0
                     or t == args.steps - 1):
@@ -381,7 +383,7 @@ def run(argv=None):
                     print(f"step {t:4d} loss={rec['loss']:.4f}")
 
     if args.checkpoint_dir:
-        with tspan("checkpoint"):
+        with span("ranl.train.checkpoint"):
             save(params, args.checkpoint_dir, step=args.steps)
         print(f"saved checkpoint to {args.checkpoint_dir}")
     if journal is not None:

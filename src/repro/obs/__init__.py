@@ -1,4 +1,4 @@
-"""Runtime observability: run journals, span tracing, metrics, and the
+"""Runtime observability: run journals, span tracing and the
 contract-drift alarm.
 
 Everything in this package runs host-side on materialized results —
@@ -11,8 +11,7 @@ for the schema, ``obs.report`` for the CLI, and the README's
 from .journal import (Journal, SCHEMA_VERSION, hlo_header, make_header,
                       read_journal, result_round_records, result_summary,
                       validate_journal, write_run_journal)
-from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                      byte_budget_for, check_byte_drift, result_metrics)
+from .metrics import byte_budget_for, check_byte_drift
 from .trace import (SpanRecord, Tracer, current_tracer, jax_profiler,
                     pop_tracer, push_tracer, span, tracing)
 
@@ -20,8 +19,7 @@ __all__ = [
     "Journal", "SCHEMA_VERSION", "hlo_header", "make_header",
     "read_journal", "result_round_records", "result_summary",
     "validate_journal", "write_run_journal",
-    "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "byte_budget_for", "check_byte_drift", "result_metrics",
+    "byte_budget_for", "check_byte_drift",
     "SpanRecord", "Tracer", "current_tracer", "jax_profiler",
     "pop_tracer", "push_tracer", "span", "tracing",
 ]

@@ -1,16 +1,27 @@
 """Span-based tracing: where a run's wall-clock actually goes.
 
-The engines' simulated clock (``hetero.cost``) prices the *modeled*
-cluster; this module meters the *host* — how long lowering, compilation,
-checkpointing and the steady-state execute loop each took — as explicit
-``with span("lower"): ...`` blocks collected by a :class:`Tracer`.
+``span(name, **meta)`` is the program's one tracing call.  It marks a
+host-side phase — the init phase, the round loop's dispatch, lowering,
+compilation, checkpointing — with two sinks:
 
-Zero-cost by default: ``span`` is a no-op ``nullcontext`` unless a
-tracer has been activated (``with tracing() as tr:`` or
-``push_tracer``), so the hooks in ``repro.run``/``repro.lower`` and the
-train CLI add nothing to untraced runs.  Spans never touch traced
-values — they wrap host-side phases only, so the compiled program is
-bit-identical with tracing on (the journal/trace acceptance rail).
+* the profiler: every span enters ``jax.profiler.TraceAnnotation(name,
+  **meta)``, so a profile taken with ``jax.profiler.trace`` (or
+  :func:`jax_profiler`) holds it as a host event named exactly ``name``,
+  ``meta`` as its stats, on the same clock as the device's operations
+  and with JAX's own host spans (lowering, backend compile) nested
+  under it;
+* the active :class:`Tracer`, when one is pushed (``with tracing() as
+  tr:`` or ``push_tracer``), which feeds the run journal and the
+  Chrome-trace export.
+
+With no profiler collecting and no tracer pushed, a span costs one
+inactive TraceMe check.  Spans never touch traced values — they wrap
+host-side phases only, so the compiled program is bit-identical with
+tracing on (the journal/trace acceptance rail).
+
+Program spans are named ``ranl.<layer>[.<phase>]``; none starts with
+``bench.``, the prefix the benchmark's own spans use to open its
+measured window.
 
 Exports:
 
@@ -18,10 +29,8 @@ Exports:
   Chrome-trace ("Perfetto"/``chrome://tracing``) JSON event form;
 * ``Tracer.span_records()`` — the journal form (``kind="span"``
   records, appended by ``obs.journal.write_run_journal``);
-* ``jax_profiler(log_dir)`` — optional passthrough to
-  ``jax.profiler.trace`` for device-level timelines (lazy import; a
-  no-op context manager when jax is unavailable is deliberately NOT
-  provided — asking for a device profile without jax is an error).
+* ``jax_profiler(log_dir)`` — passthrough to ``jax.profiler.trace``:
+  the device timeline with these spans on it.
 """
 
 from __future__ import annotations
@@ -30,6 +39,8 @@ import json
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+
+import jax
 
 __all__ = ["SpanRecord", "Tracer", "tracing", "span", "current_tracer",
            "push_tracer", "pop_tracer", "jax_profiler"]
@@ -124,20 +135,21 @@ def tracing(tracer: Tracer | None = None):
 
 @contextmanager
 def span(name: str, **meta):
-    """Record a span on the active tracer — a no-op when none is active
-    (the zero-cost default for the hooks in hot paths)."""
+    """Mark a host-side phase: a ``TraceAnnotation`` for the profiler,
+    and a record on the active tracer when one is pushed.  Yields that
+    tracer, or None."""
     t = current_tracer()
-    if t is None:
-        yield None
-        return
-    with t.span(name, **meta):
-        yield t
+    with jax.profiler.TraceAnnotation(name, **meta):
+        if t is None:
+            yield None
+            return
+        with t.span(name, **meta):
+            yield t
 
 
 @contextmanager
 def jax_profiler(log_dir: str):
-    """Passthrough to ``jax.profiler.trace(log_dir)`` — the device-level
-    (XLA) timeline next to this module's host-side phase spans."""
-    import jax
+    """Passthrough to ``jax.profiler.trace(log_dir)``: a profile of the
+    device's operations with this module's spans on the same clock."""
     with jax.profiler.trace(log_dir):
         yield log_dir

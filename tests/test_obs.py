@@ -13,18 +13,21 @@ The rails the tentpole promises:
   mismatch, stays silent at the modeled worst-case (full-mask) wire
   bytes of every combination in the committed contract matrix
   (``analysis.audit._configs`` — the 37 CONTRACTS.json entries);
-* span tracing: nesting, zero-cost inactivity, Chrome-trace export;
-* the metrics registry and the ``RanlResult`` adapter;
+* span tracing: nesting, zero-cost inactivity, Chrome-trace export,
+  and the profiler sink: ``repro.run``'s ``ranl.*`` spans land in a
+  ``jax.profiler`` trace with clean names, nested by phase, with JAX's
+  compile spans under them;
 * the report CLI: render (text/Markdown/time-to-target), diff,
   validate, and the committed ``examples/sample_journal.jsonl``;
 * train CLI integration: ``--journal``/``--trace`` leave a valid
-  journal with lower/compile/execute spans, and ``--dump-hlo --journal``
-  surfaces ``module_report``/``cost_analysis`` byte totals into the
-  journal header;
+  journal with ``ranl.train.{lower,compile,execute}`` spans, and
+  ``--dump-hlo --journal`` surfaces ``module_report``/``cost_analysis``
+  byte totals into the journal header;
 * the overhead pin: committed ``BENCH_engine.json`` obs rows within
   1.05x and the regression gate's enforcement of it.
 """
 
+import glob
 import json
 import os
 
@@ -36,9 +39,8 @@ import pytest
 import repro
 from repro.launch.mesh import make_mesh
 from repro.core import PolicyConfig, make_quadratic
-from repro.obs import (Journal, MetricsRegistry, Tracer, check_byte_drift,
-                       hlo_header, make_header, read_journal,
-                       result_metrics, span, tracing, validate_journal,
+from repro.obs import (Journal, check_byte_drift, hlo_header, make_header,
+                       read_journal, span, tracing, validate_journal,
                        write_run_journal)
 from repro.obs.report import diff, render, render_diff, render_md
 from repro.obs.report import main as report_main
@@ -292,13 +294,20 @@ def test_tracer_spans_nesting_and_chrome(tmp_path):
     assert all(e["ph"] == "X" and e["dur"] >= 0 for e in ct["traceEvents"])
 
 
+INIT_PHASES = ["ranl.init.grad", "ranl.init.hessian", "ranl.init.project",
+               "ranl.init.factor"]
+RUN_SPANS = INIT_PHASES + ["ranl.init", "ranl.rounds", "ranl.result",
+                           "ranl.run"]                   # close order
+COMPILE_SPANS = ("backend_compile_and_load", "backend_compile")
+
+
 def test_run_records_execute_span_into_journal():
     with tracing():
         j = Journal()
         repro.run(_problem(), KEY, options=_opts(num_rounds=2), journal=j)
     spans = [r for r in j.records if r["kind"] == "span"]
-    assert [s["name"] for s in spans] == ["execute"]
-    assert spans[0]["meta"] == {"engine": "scan"}
+    assert [s["name"] for s in spans] == RUN_SPANS
+    assert spans[-1]["meta"] == {"engine": "scan"}
     assert validate_journal(j) == []
 
 
@@ -307,45 +316,67 @@ def test_lower_records_span():
     with tracing() as tr:
         repro.lower(_problem(), KEY, engine="sharded", options=_opts(),
                     mesh=mesh)
-    assert [s.name for s in tr.spans] == ["lower"]
+    assert [s.name for s in tr.spans] == INIT_PHASES + ["ranl.init",
+                                                        "ranl.lower"]
 
 
-# --------------------------------------------------------------------------
-# metrics registry
-# --------------------------------------------------------------------------
-
-def test_metrics_registry_semantics():
-    reg = MetricsRegistry()
-    c = reg.counter("n")
-    c.inc(); c.inc(2.5)
-    assert reg.counter("n").value == 3.5         # same instrument back
-    with pytest.raises(ValueError):
-        c.inc(-1)
-    with pytest.raises(TypeError):
-        reg.gauge("n")                           # kind conflict
-    g = reg.gauge("g"); g.set(7); g.set(2)
-    assert g.value == 2.0
-    h = reg.histogram("h", bounds=(1, 10))
-    for v in (0.5, 5, 50):
-        h.observe(v)
-    assert h.counts == [1, 1, 1] and h.n == 3
-    assert h.mean() == pytest.approx((0.5 + 5 + 50) / 3)
-    d = reg.to_dict()
-    assert d["n"] == {"type": "counter", "value": 3.5}
-    assert d["h"]["type"] == "histogram"
+def _host_events(fn, log_dir):
+    """Run ``fn`` under ``jax.profiler.trace`` and read back the host
+    events of the ``.xplane.pb``: (name, start_ns, end_ns, stats)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(log_dir), profiler_options=opts):
+        fn()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for plane in pd.planes if plane.name.startswith("/host")
+            for line in plane.lines for ev in line.events]
 
 
-def test_result_metrics_adapter():
-    res = repro.run(_problem(), KEY, options=_opts())
-    reg = result_metrics(res)
-    d = reg.to_dict()
-    assert d["rounds_total"]["value"] == 5
-    assert d["comm_bytes_total"]["value"] == pytest.approx(
-        float(np.asarray(res.comm_bytes).sum()))
-    assert d["final_loss"]["value"] == pytest.approx(
-        float(np.asarray(res.losses)[-1]))
-    assert d["max_stale"]["type"] == "histogram"
-    assert d["round_time"]["n"] == 5
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+@pytest.mark.parametrize("engine", ["scan", "sharded"])
+def test_run_spans_nest_in_profiler_trace(engine, tmp_path):
+    # a dim no other test compiles, so the call is cold and compiles
+    prob = _problem(dim=12)
+    mesh = make_mesh((1,), ("data",)) if engine == "sharded" else None
+    evs = _host_events(
+        lambda: jax.block_until_ready(repro.run(
+            prob, KEY, engine=engine, options=_opts(num_rounds=2),
+            mesh=mesh).xs), tmp_path)
+    ranl = sorted((e for e in evs if e[0].startswith("ranl.")),
+                  key=lambda e: e[1])
+    assert [e[0] for e in ranl] == ["ranl.run", "ranl.init"] + INIT_PHASES \
+        + ["ranl.rounds", "ranl.result"]
+    assert not any(e[0].startswith("bench.") for e in evs)
+    run_, init = ranl[0], ranl[1]
+    assert run_[3] == {"engine": engine}
+    assert all(_inside(e, run_) for e in ranl[1:])
+    assert all(_inside(e, init) for e in ranl[2:6])
+    # the phases follow one another: init, then rounds, then result
+    assert all(a[2] <= b[1] for a, b in zip(ranl[2:6], ranl[3:6]))
+    assert init[2] <= ranl[6][1] and ranl[6][2] <= ranl[7][1]
+    assert any(e[0] in COMPILE_SPANS and _inside(e, run_) for e in evs)
+
+
+def test_span_meta_leaves_event_name_clean(tmp_path):
+    def body():
+        with span("ranl.probe", engine="scan", step=3) as t:
+            assert t is None                     # no tracer pushed
+        with tracing() as tr:
+            with span("ranl.probe", step=4):
+                pass
+        assert tr.spans[0].meta == (("step", 4),)
+    probes = [e for e in _host_events(body, tmp_path)
+              if "ranl.probe" in e[0]]
+    assert [e[0] for e in probes] == ["ranl.probe", "ranl.probe"]
+    assert probes[0][3] == {"engine": "scan", "step": 3}
+    assert probes[1][3] == {"step": 4}
 
 
 # --------------------------------------------------------------------------
@@ -473,9 +504,11 @@ def test_train_cli_journal_and_trace(tmp_path):
     assert [r["t"] for r in rounds] == [1, 2, 3]
     assert all("loss" in r and "step_s" in r for r in rounds)
     spans = {r["name"] for r in records if r["kind"] == "span"}
-    assert {"lower", "compile", "execute"} <= spans
+    assert {"ranl.train.lower", "ranl.train.compile",
+            "ranl.train.execute"} <= spans
     ct = json.loads(open(tpath).read())
-    assert {"lower", "compile"} <= {e["name"] for e in ct["traceEvents"]}
+    assert {"ranl.train.lower", "ranl.train.compile"} <= {
+        e["name"] for e in ct["traceEvents"]}
 
 
 def test_train_cli_log_every_thins_history(tmp_path):
