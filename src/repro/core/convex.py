@@ -23,6 +23,17 @@ def _noise_row(key, r, d: int):
     return jax.random.normal(jax.random.fold_in(key, r), (d,)) / d
 
 
+def _grad_noise(scale: float, key, d: int):
+    """Δ-scaled gradient noise of one worker: Δ·N(0, I)/√d."""
+    return scale * jax.random.normal(key, (d,)) / jnp.sqrt(d * 1.0)
+
+
+def _vmap_grads(problem, x_pruned, keys):
+    """Every worker's ``worker_grad`` at its own iterate, vmapped."""
+    return jax.vmap(problem.worker_grad)(
+        jnp.arange(problem.num_workers), x_pruned, keys)
+
+
 def _sym_noise(key, d: int):
     """Symmetric Hessian noise (z + zᵀ)/2 with z rows drawn per-row-key.
 
@@ -90,6 +101,16 @@ class Quadratic:
         noise = self.grad_noise * jax.random.normal(key, g.shape) \
             / jnp.sqrt(g.shape[0] * 1.0)
         return g + noise
+
+    def grad_path(self, use_kernel: bool, interpret: bool | None) -> str:
+        """How ``pruned_grads`` computes: always the vmapped oracle."""
+        return "vmap"
+
+    def pruned_grads(self, x_pruned, keys, *, use_kernel: bool = False,
+                     interpret: bool | None = None):
+        """(N, d) gradients of every worker at its pruned iterate (the
+        round loop's worker step); ``keys``: (N,) noise keys."""
+        return _vmap_grads(self, x_pruned, keys)
 
     def worker_hessian(self, i, x, key):
         """Stochastic ∇²F_i(x⁰, ξ): exact + symmetric noise (Frobenius σ).
@@ -254,9 +275,44 @@ class Logistic:
         z = (Xi @ x) * yi
         s = jax.nn.sigmoid(-z)                         # (n,)
         g = -(Xi.T @ (s * yi)) / yi.shape[0] + self.lam * x
-        noise = self.grad_noise * jax.random.normal(key, g.shape) \
-            / jnp.sqrt(g.shape[0] * 1.0)
-        return g + noise
+        return g + _grad_noise(self.grad_noise, key, g.shape[0])
+
+    def grad_path(self, use_kernel: bool, interpret: bool | None) -> str:
+        """``"fused"`` when ``pruned_grads`` runs the one-pass Pallas kernel
+        (``kernels.logistic_grad``), else ``"vmap"``.
+
+        The kernel runs when ``use_kernel`` is set and it compiles here: on
+        a TPU (``interpret=None``), or in interpret mode where the caller
+        asks for it (``interpret=True``; CPU users otherwise keep the jnp
+        path).  It also needs f32 data, a width whose row tile fits in
+        VMEM, and X laid out rows-minor, the layout it reads in place."""
+        from ..kernels.logistic_grad import row_block, rows_minor_layout
+        _, n, d = self.X.shape
+        if not use_kernel or self.X.dtype != jnp.float32:
+            return "vmap"
+        if interpret is None and jax.default_backend() != "tpu":
+            return "vmap"
+        if row_block(n, d) is None or not rows_minor_layout(self.X.shape):
+            return "vmap"
+        return "fused"
+
+    def pruned_grads(self, x_pruned, keys, *, use_kernel: bool = False,
+                     interpret: bool | None = None):
+        """(N, d) gradients of every worker at its pruned iterate (the
+        round loop's worker step); ``keys``: (N,) noise keys.
+
+        The fused path reads each worker's X once per call where the vmap
+        of ``worker_grad`` reads it twice; its noise is the same draw from
+        the same keys (see ``grad_path`` for when it runs)."""
+        if self.grad_path(use_kernel, interpret) == "vmap":
+            return _vmap_grads(self, x_pruned, keys)
+        from ..kernels.logistic_grad import logistic_grads
+        G = logistic_grads(self.X, self.y, x_pruned, lam=self.lam,
+                           interpret=interpret)
+        if self.grad_noise:
+            G = G + jax.vmap(lambda k: _grad_noise(
+                self.grad_noise, k, self.dim))(keys)
+        return G
 
     def worker_hessian(self, i, x, key):
         Xi, yi = self.X[i], self.y[i]

@@ -13,8 +13,10 @@ Engine layout:
   workers instead of a host loop, and the Cholesky factor of [H]_μ is
   computed once (not re-factored every round);
 * the round loop is a single ``jax.lax.scan`` — mask sampling, the pruned
-  gradient ``vmap``, server aggregation, and the projected-Newton step all
-  live in the scanned body, so all rounds trace and compile once;
+  gradients (the problem's ``pruned_grads``: a ``vmap`` of its oracle, or
+  for dense logistic regression one Pallas pass over each worker's X),
+  server aggregation, and the projected-Newton step all live in the
+  scanned body, so all rounds trace and compile once;
 * coverage / communication / τ* diagnostics ride the scan outputs instead
   of host-side Python accumulators;
 * ``run_ranl_batch`` vmaps init + rounds over seeds: many independent runs
@@ -161,6 +163,11 @@ class RanlResult:
                                # a hierarchical run (``xs`` is their pod
                                # mean — the consensus estimate); None
                                # for flat runs
+    grad_path: str = "vmap"    # how the round loop computed the workers'
+                               # gradients: "fused" (one Pallas pass over
+                               # each worker's data, the problem's
+                               # ``pruned_grads``) or "vmap" (its
+                               # ``worker_grad`` per worker)
 
 
 def _init_phase(problem, k_init, *, mu: float, lr: float, curvature: str,
@@ -388,13 +395,12 @@ def _scan_rounds(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
             problem, k_loop, x1, C0, cho_c, hdiag, cost,
             num_rounds=num_rounds, num_regions=num_regions,
             controller=controller, mu=mu, lr=lr, curvature=curvature,
+            use_kernel=use_kernel, interpret=interpret,
             cho_lower=cho_lower, qspec=qspec, comp=comp, hspec=hspec)
     N, d = problem.num_workers, problem.dim
     Q = num_regions
     region_ids = contiguous_regions(d, Q)
     sizes_q = region_sizes(region_ids, Q)
-    worker_ids = jnp.arange(N)
-    grad_pruned = jax.vmap(problem.worker_grad, in_axes=(0, 0, 0))
     pod_wire = _pod_wire_bytes(comp, d)
 
     def body(carry, t):
@@ -405,7 +411,8 @@ def _scan_rounds(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
         Mx = expand_mask(M, region_ids)                  # (N, d) bool
         x_pruned = jnp.where(Mx, x[None, :], 0.0)        # x ⊙ m_i
         gk = jax.random.split(jax.random.fold_in(kt, 7), N)
-        G = grad_pruned(worker_ids, x_pruned, gk) * Mx   # ∇F_i ⊙ m_i
+        G = problem.pruned_grads(x_pruned, gk, use_kernel=use_kernel,
+                                 interpret=interpret) * Mx  # ∇F_i ⊙ m_i
         ubytes = uplink_bytes(comp, M, sizes_q)          # (N,) wire model
         if qspec is not None:
             work = (M * sizes_q[None, :]).sum(axis=1)
@@ -498,6 +505,7 @@ def _scan_rounds(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
 def _hier_scan_rounds(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
                       num_rounds: int, num_regions: int, controller,
                       mu: float, lr: float, curvature: str,
+                      use_kernel: bool, interpret: bool | None,
                       cho_lower: bool, qspec: QuorumSpec | None,
                       comp: CompressionSpec | None, hspec: HierarchySpec):
     """Hierarchical pod-of-pods rounds in one program (scan engine).
@@ -542,8 +550,6 @@ def _hier_scan_rounds(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
     Q = num_regions
     region_ids = contiguous_regions(d, Q)
     sizes_q = region_sizes(region_ids, Q)
-    worker_ids = jnp.arange(N)
-    grad_pruned = jax.vmap(problem.worker_grad, in_axes=(0, 0, 0))
     hcomp = parse_compression(hspec.compression)
     pod_wire = _pod_wire_bytes(hcomp, d)
 
@@ -556,7 +562,8 @@ def _hier_scan_rounds(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
         x_w = jnp.repeat(x, n_pod, axis=0)               # worker's pod iterate
         x_pruned = jnp.where(Mx, x_w, 0.0)
         gk = jax.random.split(jax.random.fold_in(kt, 7), N)
-        G = grad_pruned(worker_ids, x_pruned, gk) * Mx
+        G = problem.pruned_grads(x_pruned, gk, use_kernel=use_kernel,
+                                 interpret=interpret) * Mx
         ubytes = uplink_bytes(comp, M, sizes_q)
         Gp = G.reshape(pods, n_pod, d)
         Mxp = Mx.reshape(pods, n_pod, d)
@@ -2014,8 +2021,18 @@ def _run_scan(problem, key, opts: RanlOptions, *, controller=None,
             xs=xs, dist_sq=dist, losses=losses, coverage=cov,
             comm_floats=comm, tau_star=int(tau), tau_covered=int(tau_cov),
             round_time=times, max_stale=stale, comm_bytes=cbytes,
-            pod_bytes=pbytes, xs_pods=xs_pods),
+            pod_bytes=pbytes, xs_pods=xs_pods,
+            grad_path=problem.grad_path(static["use_kernel"],
+                                        static["interpret"])),
             opts.record_every)
+
+
+def _batch_use_kernel(opts: RanlOptions, mesh, axis_name: str) -> bool:
+    """The batch engine's ``use_kernel``: XLA cannot partition a Pallas
+    call, so seeds spread over several devices take the jnp forms, which
+    each device runs for its own seeds."""
+    return bool(opts.use_kernel) and (mesh is None
+                                      or mesh.shape[axis_name] == 1)
 
 
 def _run_batch(problem, keys, opts: RanlOptions, *, mesh=None,
@@ -2030,7 +2047,9 @@ def _run_batch(problem, keys, opts: RanlOptions, *, mesh=None,
     With ``mesh``, the seed axis is sharded across the devices of the
     mesh's ``axis_name`` axis (the problem is replicated): B independent
     runs execute B/n_dev-per-device with zero cross-run communication.
-    Requires B divisible by the axis extent.
+    Requires B divisible by the axis extent.  Over more than one device
+    the runs take the jnp forms, not the Pallas kernels
+    (``_batch_use_kernel``).
 
     ``controller``/``cost`` close the heterogeneity loop per seed (each
     vmapped run carries its own controller state and telemetry);
@@ -2058,6 +2077,7 @@ def _run_batch(problem, keys, opts: RanlOptions, *, mesh=None,
         keys = jax.device_put(keys, NamedSharding(mesh, P(axis_name)))
         problem = jax.device_put(problem, NamedSharding(mesh, P()))
         cost = jax.device_put(cost, NamedSharding(mesh, P()))
+    use_kernel = _batch_use_kernel(opts, mesh, axis_name)
     projection = opts.projection or "eigh"
     cfg = _config(problem, mu=opts.mu, lr=opts.lr,
                   curvature=opts.curvature,
@@ -2067,7 +2087,7 @@ def _run_batch(problem, keys, opts: RanlOptions, *, mesh=None,
      cbytes, pbytes) = _batch_jit(
         problem, keys, cost, num_rounds=int(opts.num_rounds),
         num_regions=int(opts.num_regions), controller=ctrl,
-        use_kernel=bool(opts.use_kernel), interpret=None,
+        use_kernel=use_kernel, interpret=None,
         projection=projection,
         ns_iters=opts.ns_iters if opts.ns_iters == "auto"
         else int(opts.ns_iters),
@@ -2080,7 +2100,8 @@ def _run_batch(problem, keys, opts: RanlOptions, *, mesh=None,
         xs=xs, dist_sq=dist, losses=losses, coverage=cov,
         comm_floats=comm, tau_star=tau, tau_covered=tau_cov,
         round_time=times, max_stale=stale, comm_bytes=cbytes,
-        pod_bytes=pbytes, xs_pods=xs_pods),
+        pod_bytes=pbytes, xs_pods=xs_pods,
+        grad_path=problem.grad_path(use_kernel, None)),
         opts.record_every)
 
 
@@ -2249,8 +2270,8 @@ def trace_ranl(problem, key, opts: RanlOptions = RanlOptions(), *,
                 problem, jnp.asarray(keys), cost,
                 num_rounds=int(opts.num_rounds),
                 num_regions=int(opts.num_regions), controller=ctrl,
-                use_kernel=bool(opts.use_kernel), interpret=None,
-                projection=projection,
+                use_kernel=_batch_use_kernel(opts, mesh, axis_name),
+                interpret=None, projection=projection,
                 ns_iters=opts.ns_iters if opts.ns_iters == "auto"
                 else int(opts.ns_iters),
                 qspec=opts.quorum_spec(), comp=opts.compression_spec(),

@@ -1,5 +1,7 @@
 """Unit + property tests for the paper-faithful core (Algorithm 1)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -544,3 +546,111 @@ def test_staleness_floor_monotone():
                                            heterogeneous=False))
         floors.append(float(np.asarray(res.dist_sq)[-5:].mean()))
     assert floors[0] < floors[1] < floors[2]
+
+
+# --------------------------------------------------------------------------
+# the round loop's worker gradients: fused Pallas pass vs vmapped oracle
+# --------------------------------------------------------------------------
+
+def _rows_minor(monkeypatch):
+    """The CPU lays X out row-major, which the kernel does not read; have
+    the layout query answer as the TPU does at the convex cell's shape."""
+    from repro.kernels import logistic_grad
+    monkeypatch.setattr(logistic_grad, "rows_minor_layout",
+                        lambda *a, **kw: True)
+
+
+def _kernel_in_interpret_mode(monkeypatch):
+    """Off a TPU the engines keep the jnp path; ask for the interpret-mode
+    kernel the way a test may, by steering the round loop's statics."""
+    from repro.core import ranl
+    scan_args = ranl._scan_args
+
+    def with_interpret(*a, **kw):
+        args, static = scan_args(*a, **kw)
+        return args, dict(static, interpret=True)
+
+    monkeypatch.setattr(ranl, "_scan_args", with_interpret)
+    _rows_minor(monkeypatch)
+
+
+def test_scan_engine_fused_logistic_grads_match_vmap(monkeypatch):
+    """Dense logistic rounds through the one-pass kernel follow the
+    vmapped ``worker_grad`` rounds: iterates within 1e-5 over 10 rounds,
+    equal uplink counts; each run records the path it took.  1,100 rows
+    per worker make three row tiles, the last one ragged and masked."""
+    from repro.core import make_logistic
+    from repro.kernels.logistic_grad import BLOCK_N
+    prob = make_logistic(KEY, num_workers=4, per_worker=1100, dim=24,
+                         lam=0.05, heterogeneity=0.5)
+    assert 2 * BLOCK_N < 1100 < 3 * BLOCK_N
+    pol = PolicyConfig(keep_prob=0.5, tau_star=1)
+    _kernel_in_interpret_mode(monkeypatch)
+    fused = repro.run(prob, KEY, num_rounds=10, num_regions=4, policy=pol)
+    plain = repro.run(prob, KEY, num_rounds=10, num_regions=4, policy=pol,
+                      use_kernel=False)
+    assert fused.grad_path == "fused" and plain.grad_path == "vmap"
+    np.testing.assert_allclose(fused.xs, plain.xs, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(fused.comm_floats),
+                                  np.asarray(plain.comm_floats))
+
+
+def test_quadratic_round_loop_keeps_vmap_path(monkeypatch):
+    """Quadratic problems take the vmapped oracle whatever the kernel
+    statics say: the same jaxpr as ``vmap(worker_grad)`` and no
+    ``pallas_call`` in the round program."""
+    prob = make_quadratic(KEY, num_workers=4, dim=16, kappa=10.0,
+                          coupling=0.0, num_regions=4, grad_noise=0.1)
+    N, d = prob.num_workers, prob.dim
+    xp = jnp.ones((N, d))
+    keys = jax.random.split(KEY, N)
+    old = jax.make_jaxpr(lambda xp, k: jax.vmap(
+        prob.worker_grad, in_axes=(0, 0, 0))(jnp.arange(N), xp, k))(xp, keys)
+    new = jax.make_jaxpr(lambda xp, k: prob.pruned_grads(
+        xp, k, use_kernel=True, interpret=True))(xp, keys)
+    assert str(new) == str(old)
+
+    from repro.core import ranl
+    _kernel_in_interpret_mode(monkeypatch)
+    opts = repro.RanlOptions(num_rounds=3, num_regions=4)
+    args, static = ranl._scan_args(prob, KEY, opts)
+    assert static["interpret"] is True and static["use_kernel"]
+    jaxpr = jax.make_jaxpr(functools.partial(ranl._scan_rounds,
+                                             **static))(*args)
+    assert "pallas_call" not in str(jaxpr)
+    assert repro.run(prob, KEY, num_rounds=3,
+                     num_regions=4).grad_path == "vmap"
+
+
+def test_batch_engine_keeps_vmap_grads_on_a_seed_sharded_mesh(monkeypatch):
+    """XLA cannot partition a Pallas call: the batch engine's program
+    holds the kernel when its seeds sit on one device and none when
+    they are spread over two, where each device runs the vmapped
+    gradients of its own seeds."""
+    from jax.sharding import AbstractMesh
+    from repro.core import make_logistic
+    prob = make_logistic(KEY, num_workers=4, per_worker=300, dim=24,
+                         lam=0.05, heterogeneity=0.5)
+    keys = jax.random.split(KEY, 2)
+    _rows_minor(monkeypatch)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def program(n_dev):
+        return str(repro.trace(prob, keys, engine="batch", num_rounds=2,
+                               num_regions=4,
+                               mesh=AbstractMesh((n_dev,), ("data",))))
+
+    assert "pallas_call" in program(1)
+    assert "pallas_call" not in program(2)
+
+
+def test_logistic_keeps_vmap_path_where_x_is_not_rows_minor():
+    """X laid out row-major (the CPU's layout) would need a copy of X per
+    call to feed the kernel: the problem keeps the vmapped oracle even
+    where the interpret-mode kernel is asked for."""
+    from repro.core import make_logistic
+    from repro.kernels.logistic_grad import rows_minor_layout
+    prob = make_logistic(KEY, num_workers=4, per_worker=300, dim=24,
+                         lam=0.05, heterogeneity=0.5)
+    assert not rows_minor_layout(prob.X.shape)
+    assert prob.grad_path(True, True) == "vmap"

@@ -140,6 +140,60 @@ def test_kernel_consistent_with_core_aggregation():
 
 
 # --------------------------------------------------------------------------
+# logistic_grads: one pass over each worker's X
+# --------------------------------------------------------------------------
+
+_LOGISTIC_CASES = {
+    # name: (workers, rows, width, pruned, grad_noise, seeds); the kernel
+    # tiles rows by its default 512
+    "rows_a_tile_multiple": (3, 2048, 128, False, 0.0, 0),
+    "ragged_rows": (3, 1500, 128, False, 0.0, 0),
+    "rows_within_one_tile": (3, 300, 128, False, 0.0, 0),
+    "width_not_lane_multiple": (2, 1500, 250, False, 0.0, 0),
+    "width_of_the_convex_cell": (2, 600, 2000, False, 0.0, 0),
+    "pruned_zero_regions": (4, 1100, 250, True, 0.0, 0),
+    "grad_noise": (4, 300, 40, True, 0.3, 0),
+    "vmapped_over_seeds": (3, 1100, 40, True, 0.1, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LOGISTIC_CASES))
+def test_logistic_grads_match_vmapped_worker_grad(case, monkeypatch):
+    """The fused kernel (interpret mode) equals
+    ``vmap(Logistic.worker_grad)`` at the pruned iterates, noise included,
+    also under a leading vmap axis as the batch engine runs it."""
+    from repro.core import Logistic, contiguous_regions, expand_mask
+    from repro.kernels import logistic_grad
+    N, n, d, pruned, noise, seeds = _LOGISTIC_CASES[case]
+    ks = jax.random.split(jax.random.fold_in(KEY, 1), 5)
+    X = jax.random.normal(ks[3], (N, n, d)) / np.sqrt(d) \
+        + 0.5 * jax.random.normal(ks[4], (N, 1, d))      # non-IID shifts
+    y = jnp.where(jax.random.uniform(ks[4], (N, n)) < 0.5, 1.0, -1.0)
+    prob = Logistic(X=X, y=y, lam=0.05, grad_noise=noise, hess_noise=0.0,
+                    x_star=jnp.zeros(d), mu=0.05, L_g=1.0)
+    x = jax.random.normal(ks[0], (max(seeds, 1), N, d))
+    if pruned:                       # whole regions of each worker zeroed
+        keep = jax.random.uniform(ks[1], (max(seeds, 1), N, 5)) < 0.5
+        x = jnp.where(expand_mask(keep, contiguous_regions(d, 5)), x, 0.0)
+    keys = jax.random.split(ks[2], N)
+    # the CPU lays X out row-major; answer as the TPU does for rows
+    monkeypatch.setattr(logistic_grad, "rows_minor_layout",
+                        lambda *a, **kw: True)
+    assert prob.grad_path(True, True) == "fused"
+
+    def fused(xp):
+        return prob.pruned_grads(xp, keys, use_kernel=True, interpret=True)
+
+    def oracle(xp):
+        return jax.vmap(prob.worker_grad)(jnp.arange(N), xp, keys)
+
+    got = jax.vmap(fused)(x) if seeds else fused(x[0])
+    want = jax.vmap(oracle)(x) if seeds else oracle(x[0])
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6 * scale)
+
+
+# --------------------------------------------------------------------------
 # flash attention
 # --------------------------------------------------------------------------
 

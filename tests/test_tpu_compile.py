@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.logistic_grad import logistic_grads, rows_minor_layout
 from repro.kernels.region_aggregate import ranl_update, region_aggregate
 
 N = 16
@@ -62,3 +63,36 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, d):
         args = (sh["tile"], sh["mask"], sh["tile"])
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape,rows_minor", [
+    ((16, 25000, 2000), True),      # the convex cell: rows ride the lanes
+    ((16, 5000, 200), True),
+    ((16, 25000, 2048), False),     # a lane-multiple width keeps d minor
+])
+def test_logistic_grads_layout_query_on_v5e(topo, shape, rows_minor):
+    """The chip's default layout of X decides whether the one-pass kernel
+    can read it in place; where it keeps d minor, ``Logistic`` keeps its
+    vmap path."""
+    assert rows_minor_layout(shape, device=topo.devices[0]) is rows_minor
+
+
+@pytest.mark.parametrize("shape", [
+    (16, 25000, 2000),              # the convex cell
+    (16, 5000, 200),                # ragged last row tile
+])
+def test_logistic_grads_compiles_for_v5e(one_chip, shape):
+    """The one-pass logistic kernel compiles for X laid out rows-minor and
+    reads X in place: no copy of X."""
+    N, n, d = shape
+
+    def s(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    fn = partial(logistic_grads, lam=1e-4, interpret=False)
+    hlo = jax.jit(fn).lower(s(N, n, d), s(N, n), s(N, d)).compile() \
+        .as_text()
+    assert "tpu_custom_call" in hlo
+    x_shape = f"f32[{N},{n},{d}]"
+    assert not [line for line in hlo.splitlines()
+                if " copy(" in line and x_shape in line]
