@@ -1,4 +1,4 @@
-"""Config registry: 10 assigned architectures + input shapes."""
+"""Config registry: 11 architectures + input shapes."""
 
 from .base import (  # noqa: F401
     INPUT_SHAPES,
@@ -20,6 +20,7 @@ from . import (  # noqa: F401  (registration side effects)
     musicgen_medium,
     rwkv6_3b,
     phi4_mini_3_8b,
+    moonlight_16b_a3b,
 )
 
 ALL_ARCHS = [
@@ -33,4 +34,5 @@ ALL_ARCHS = [
     "musicgen-medium",
     "rwkv6-3b",
     "phi4-mini-3.8b",
+    "moonlight-16b-a3b",
 ]

@@ -27,11 +27,22 @@ class ModelConfig:
     head_dim: int = 0           # 0 -> d_model // num_heads
     qk_norm: bool = False
     rope_theta: float = 1_000_000.0
+    norm_eps: float = 1e-6      # RMSNorm epsilon
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0        # experts the router scores over
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0       # experts whose weights are held (0 -> all)
+    moe_d_ff: int = 0           # routed/shared expert width (0 -> d_ff)
+    num_shared_experts: int = 0
+    first_dense_layers: int = 0  # leading layers with a dense FFN
+    router_score: str = "softmax"  # softmax | sigmoid
+    routed_scale: float = 1.0   # scale of the renormalised top-k weights
     router_aux_weight: float = 0.01
+    # --- latent attention (MLA): kv_lora_rank > 0 turns it on ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM / hybrid ---
     ssm_state: int = 0
     ssm_conv_width: int = 4
@@ -59,6 +70,18 @@ class ModelConfig:
         return self.rwkv_head_dim
 
     @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def uses_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def num_rwkv_heads(self) -> int:
         return self.d_model // self.rwkv_head_dim
 
@@ -70,29 +93,46 @@ class ModelConfig:
     def uses_ssm(self) -> bool:
         return self.family in ("ssm", "hybrid") and self.ssm_state > 0
 
-    def param_count(self) -> int:
-        """Analytic parameter count (embedding + blocks + head)."""
-        d, ff, v = self.d_model, self.d_ff, self.vocab_size
+    def _attention_params(self) -> int:
+        d, H = self.d_model, self.num_heads
+        if self.uses_mla:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            qk = self.qk_nope_head_dim + rope
+            return (d * H * qk + d * (r + rope) + r
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)
         hd = self.resolved_head_dim
+        return 2 * d * H * hd + 2 * d * self.num_kv_heads * hd
+
+    def param_count(self, routed_experts: int | None = None) -> int:
+        """Analytic parameter count (embedding + blocks + head).  Each MoE
+        layer counts ``routed_experts`` routed experts (default: the held
+        ones) beside its router, shared experts and selection bias."""
+        d, ff, v = self.d_model, self.d_ff, self.vocab_size
         per_layer = 0
         if self.uses_attention and not self.attn_free:
-            q = d * self.num_heads * hd
-            kv = 2 * d * self.num_kv_heads * hd
-            o = self.num_heads * hd * d
-            per_layer += q + kv + o + 2 * d  # + norms
+            per_layer += self._attention_params() + 2 * d  # + norms
         if self.attn_free:  # rwkv time-mix
             h = self.num_rwkv_heads * self.rwkv_head_dim
             per_layer += 5 * d * h + h * d + 2 * d
         if self.uses_ssm:
             di = d
             per_layer += d * 2 * di + di * (2 * self.ssm_state + 1) + di * d
+        if self.attn_free:  # rwkv channel mix
+            ffn = dense_ffn = 2 * d * int(ff)
+        else:
+            dense_ffn = ffn = 3 * d * ff
         if self.num_experts:
-            per_layer += self.num_experts * 3 * d * ff + d * self.num_experts
-        elif not self.attn_free:
-            per_layer += 3 * d * ff
-        else:  # rwkv channel mix
-            per_layer += 2 * d * int(ff)
-        total = self.num_layers * per_layer
+            E, fe = self.num_experts, self.expert_d_ff
+            k = self.held_experts if routed_experts is None \
+                else routed_experts
+            ffn = (k * 3 * d * fe + d * E
+                   + 3 * d * fe * self.num_shared_experts)
+            if self.router_score == "sigmoid":
+                ffn += E                        # selection bias
+        nd = self.first_dense_layers
+        total = (self.num_layers * per_layer + nd * dense_ffn
+                 + (self.num_layers - nd) * ffn)
         total += v * d  # embedding
         if not self.tie_embeddings:
             total += d * v
@@ -106,10 +146,7 @@ class ModelConfig:
         """Params touched per token (MoE: only routed experts)."""
         if not self.num_experts:
             return self.param_count()
-        dense_like = dataclasses.replace(
-            self, num_experts=0, experts_per_token=0,
-            d_ff=self.d_ff * self.experts_per_token)
-        return dense_like.param_count()
+        return self.param_count(routed_experts=self.experts_per_token)
 
 
 @dataclass(frozen=True)
@@ -170,6 +207,14 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         vocab_size=512,
         num_experts=min(cfg.num_experts, 4),
         experts_per_token=min(cfg.experts_per_token, 2),
+        experts_held=min(cfg.experts_held, 4),
+        moe_d_ff=min(cfg.moe_d_ff, 128),
+        num_shared_experts=min(cfg.num_shared_experts, 2),
+        first_dense_layers=min(cfg.first_dense_layers, 1),
+        kv_lora_rank=min(cfg.kv_lora_rank, 64),
+        qk_nope_head_dim=min(cfg.qk_nope_head_dim, 32),
+        qk_rope_head_dim=min(cfg.qk_rope_head_dim, 16),
+        v_head_dim=min(cfg.v_head_dim, 32),
         ssm_state=min(cfg.ssm_state, 8),
         rwkv_head_dim=64,
         vision_embed_dim=96,

@@ -4,7 +4,8 @@ Conventions (DESIGN.md §5):
   * batch/worker axes shard over ("pod", "data");
   * tensor-parallel over "model": attention heads (q out-dim), FFN width,
     vocab, MoE experts, SSM inner width, RWKV head projections;
-  * small glue (norms, token-shift mixes, routers) replicated;
+  * small glue (norms, token-shift mixes, routers and their selection
+    bias, latent attention's down-projection) replicated;
   * decode caches: batch over data when divisible, else the window/sequence
     dim (long_500k batch=1 → sequence-parallel cache).
 """
@@ -42,7 +43,7 @@ def _param_spec(names: list[str], shape, model_shards: int,
     divisibility.
     """
     name = names[-1]
-    in_layers = "layers" in names
+    in_layers = "layers" in names or "dense_layers" in names
     ndim = len(shape)
     off = 1 if in_layers else 0          # skip the stacked-layer axis
 
@@ -91,8 +92,10 @@ def _param_spec(names: list[str], shape, model_shards: int,
     if name == "final_norm":
         return lay(None)
 
-    # attention / generic projections (output dim on "model")
-    if name in ("wq", "wk", "wv", "in_proj", "w_r", "w_k", "w_v", "w_g"):
+    # attention / generic projections (output dim on "model"); latent
+    # attention's wkv_b splits by heads, its wkv_a and kv_norm replicate
+    if name in ("wq", "wk", "wv", "wkv_b", "in_proj", "w_r", "w_k", "w_v",
+                "w_g"):
         return lay(None, "model")
     if name in ("wo", "w_o", "out_proj", "down"):
         if ndim - off == 3:                             # MoE (E, ff, d)

@@ -39,13 +39,26 @@ from ..obs import Journal, Tracer, make_header, span, tracing
 from ..optim import (AdamWConfig, RanlLLMConfig, adamw_init, adamw_step,
                      init_state, train_step)
 from ..checkpoint import save
+from ..models.sharding import use_mesh
 from .cache import use_compile_cache
 
 
+def attn_block(seq: int) -> int:
+    """Query and key block of the blocked attention over ``seq`` tokens:
+    1,024, or half the sequence beyond 2,048 (fewer, larger blocks
+    compile faster at long sequences), never more than ``seq``."""
+    return min(seq, max(1024, seq // 2))
+
+
 def build_loss(cfg, q_chunk=1024, kv_chunk=1024, remat=True):
+    """The train loss; with MoE layers it also returns their routing
+    counters (``loss_fn.has_aux``), which ``train_step`` reports."""
+    with_stats = bool(cfg.num_experts)
+
     def loss_fn(params, batch):
         return lm_loss(params, batch, cfg, q_chunk=q_chunk,
-                       kv_chunk=kv_chunk, remat=remat)
+                       kv_chunk=kv_chunk, remat=remat, with_stats=with_stats)
+    loss_fn.has_aux = with_stats
     return loss_fn
 
 
@@ -60,6 +73,9 @@ def run(argv=None):
     ap.add_argument("--vocab", type=int, default=0,
                     help="cut the registered config to this vocabulary "
                          "size (widths unchanged; 0 = published vocab)")
+    ap.add_argument("--experts-held", type=int, default=0,
+                    help="hold this many of the routed experts (the "
+                         "router still scores all of them; 0 = all)")
     ap.add_argument("--optimizer", default="ranl",
                     choices=["ranl", "adamw"])
     ap.add_argument("--steps", type=int, default=10)
@@ -159,21 +175,30 @@ def _train(args, tracer):
 
     cfg = get_config(args.arch)
     if args.smoke:
-        if args.layers or args.vocab:
-            raise SystemExit("--layers/--vocab cut the published config; "
-                             "--smoke already replaces it")
+        if args.layers or args.vocab or args.experts_held:
+            raise SystemExit("--layers/--vocab/--experts-held cut the "
+                             "published config; --smoke already replaces "
+                             "it")
         cfg = smoke_variant(cfg)
-    elif args.layers or args.vocab:
-        if args.layers < 0 or args.vocab < 0:
-            raise SystemExit("--layers/--vocab must be positive")
+    elif args.layers or args.vocab or args.experts_held:
+        if min(args.layers, args.vocab, args.experts_held) < 0:
+            raise SystemExit("--layers/--vocab/--experts-held must be "
+                             "positive")
+        if args.experts_held > cfg.num_experts:
+            raise SystemExit(f"--experts-held {args.experts_held}: "
+                             f"{args.arch} routes over {cfg.num_experts}")
         cut = {}
         if args.layers:
             cut["num_layers"] = args.layers
         if args.vocab:
             cut["vocab_size"] = args.vocab
+        if args.experts_held:
+            cut["experts_held"] = args.experts_held
         cfg = dataclasses.replace(cfg, **cut)
+        held = (f" experts held={cfg.held_experts}/{cfg.num_experts}"
+                if cfg.num_experts else "")
         print(f"config cut: {args.arch} layers={cfg.num_layers} "
-              f"vocab={cfg.vocab_size} (d_model={cfg.d_model} "
+              f"vocab={cfg.vocab_size}{held} (d_model={cfg.d_model} "
               f"d_ff={cfg.d_ff} heads={cfg.num_heads}/{cfg.num_kv_heads} "
               f"x{cfg.resolved_head_dim} as published)")
     mesh = None
@@ -200,12 +225,19 @@ def _train(args, tracer):
         mesh = make_mesh((args.data_shards,), ("data",))
         print(f"mesh: {args.data_shards}-way ('data',) over "
               f"{jax.devices()[0].platform}")
+    # model code reads the mesh (sharding hints, the expert layer's
+    # shard_map) from the ambient context
+    with use_mesh(mesh) if mesh is not None else nullcontext():
+        return _train_loop(args, tracer, cfg, mesh)
+
+
+def _train_loop(args, tracer, cfg, mesh):
     key = jax.random.PRNGKey(args.seed)
     kp, kd, ko = jax.random.split(key, 3)
 
     params = init_model(cfg, kp)
-    loss_fn = build_loss(cfg, q_chunk=min(1024, args.seq),
-                         kv_chunk=min(1024, args.seq))
+    chunk = attn_block(args.seq)
+    loss_fn = build_loss(cfg, q_chunk=chunk, kv_chunk=chunk)
     batch0 = make_batch(cfg, jax.random.fold_in(kd, 0),
                         args.batch, args.seq, pattern=args.pattern)
 
@@ -349,10 +381,17 @@ def _train(args, tracer):
                 if journal is not None:
                     journal.write({"kind": "round", "t": t + 1, **metrics})
                 if t % args.log_every == 0:
+                    moe_note = ""
+                    if "held_rows" in metrics:
+                        moe_note = (
+                            f" rows held={metrics['held_rows']:.0f} "
+                            f"absent={metrics['absent_rows']:.0f} "
+                            f"dropped={metrics['dropped_rows']:.0f} "
+                            f"load_max={metrics['load_max_ratio']:.2f}")
                     print(f"step {t:4d} loss={metrics['loss']:.4f} "
                           f"cov={metrics['coverage']:.2f} "
                           f"uplink={metrics['uplink_frac']:.2f} "
-                          f"({metrics['step_s']:.2f}s){sim_note}")
+                          f"({metrics['step_s']:.2f}s){sim_note}{moe_note}")
     else:
         acfg = AdamWConfig(lr=1e-3)
         state = adamw_init(params, acfg)
@@ -364,7 +403,10 @@ def _train(args, tracer):
 
         @jax.jit
         def astep(params, state, batch):
-            loss, g = jax.value_and_grad(loss_fn)(params, batch)
+            loss, g = jax.value_and_grad(loss_fn, has_aux=loss_fn.has_aux)(
+                params, batch)
+            if loss_fn.has_aux:
+                loss = loss[0]
             params, state = adamw_step(params, state, g, acfg)
             return params, state, loss
 

@@ -1,4 +1,5 @@
-"""GQA attention: blocked (flash-style) softmax, sliding window, KV cache.
+"""GQA and latent (MLA) attention: blocked (flash-style) softmax, sliding
+window, KV cache (GQA only).
 
 The blocked implementation unrolls the q/kv block loops in Python so the
 per-layer HLO is straight-line: XLA's ``cost_analysis`` then counts every
@@ -55,13 +56,14 @@ def blocked_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
     split, saving the groups-x kv read amplification that a repeat-KV
     formulation pays; measured on decode in EXPERIMENTS.md §Perf pair 4).
 
-    q: (B, Sq, H, hd) with H = KV*G; k, v: (B, Skv, KV, hd).
+    q, k: (B, Sq|Skv, H|KV, hd) with H = KV*G; v: (B, Skv, KV, hv), whose
+    head dim may differ from the query/key one (latent attention).
     q_pos: (B, Sq) int32; k_pos: (B, Skv) int32 (−1 marks empty cache slots).
     static_positions: True when positions are literally ``arange`` (train /
     prefill) — enables trace-time skipping of fully-masked blocks.
     """
     B, Sq, H, hd = q.shape
-    Skv, KV = k.shape[1], k.shape[2]
+    Skv, KV, hv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
     q_chunk = min(q_chunk, Sq)
@@ -78,7 +80,7 @@ def blocked_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
         qpb = q_pos[:, q0:q1]
         m = jnp.full((B, KV, G, qc), NEG_INF, jnp.float32)
         l = jnp.zeros((B, KV, G, qc), jnp.float32)
-        acc = jnp.zeros((B, KV, G, qc, hd), jnp.float32)
+        acc = jnp.zeros((B, KV, G, qc, hv), jnp.float32)
         for j in range(n_kv):
             k0, k1_ = j * kv_chunk, min((j + 1) * kv_chunk, Skv)
             if static_positions:
@@ -100,8 +102,8 @@ def blocked_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
             acc = acc * corr[..., None] \
                 + jnp.einsum("bcgqk,bkch->bcgqh", p, vb)
             m = m_new
-        out = acc / jnp.maximum(l, 1e-30)[..., None]       # (B,KV,G,qc,hd)
-        out = out.transpose(0, 3, 1, 2, 4).reshape(B, qc, H, hd)
+        out = acc / jnp.maximum(l, 1e-30)[..., None]       # (B,KV,G,qc,hv)
+        out = out.transpose(0, 3, 1, 2, 4).reshape(B, qc, H, hv)
         out_blocks.append(out)
     return jnp.concatenate(out_blocks, axis=1).astype(q.dtype)
 
@@ -155,6 +157,48 @@ def apply_attention(params, x, cfg, positions, *, cache=None, pos=None,
 
     out = out.reshape(B, S, H * hd) @ params["wo"]
     return out, new_cache
+
+
+def init_mla(cfg, key, dtype=jnp.float32):
+    """Latent attention with a direct q projection (no q latent)."""
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(k1, (d, H * (nope + rope)), dtype),
+        "wkv_a": dense_init(k2, (d, r + rope), dtype),
+        "kv_norm": jnp.ones((r,), dtype),
+        "wkv_b": dense_init(k3, (r, H * (nope + hv)), dtype),
+        "wo": dense_init(k4, (H * hv, d), dtype),
+    }
+
+
+def apply_mla(params, x, cfg, positions, *, q_chunk: int = 1024,
+              kv_chunk: int = 1024):
+    """Latent attention (DeepSeek-V2/V3), train and prefill.
+
+    q = x W_q gives H heads of (nope + rope) dims; [c, k_rope] = x W_kva;
+    [k_nope, v] = RMSNorm(c) W_kvb per head; rotary on q_rope and on the
+    one k_rope, which every head shares; softmax at (nope + rope)^-1/2.
+    """
+    B, S, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with jax.named_scope("mla"):
+        q = (x @ params["wq"]).reshape(B, S, H, nope + rope)
+        kv_a = x @ params["wkv_a"]
+        c = rms_norm(kv_a[..., :r], params["kv_norm"], cfg.norm_eps)
+        kv = (c @ params["wkv_b"]).reshape(B, S, H, nope + hv)
+        q_rope = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+        k_rope = apply_rope(kv_a[..., None, r:], positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, H, rope))],
+            axis=-1)
+        out = blocked_attention(q, k, kv[..., nope:], positions, positions,
+                                q_chunk=q_chunk, kv_chunk=kv_chunk,
+                                static_positions=True)
+        return out.reshape(B, S, H * hv) @ params["wo"]
 
 
 def init_cache(cfg, batch: int, cache_len: int, dtype=jnp.bfloat16):

@@ -12,7 +12,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .attention import apply_attention, init_attention, init_cache
+from .attention import (apply_attention, apply_mla, init_attention,
+                        init_cache, init_mla)
 from .common import embed_init, dense_init, rms_norm, softmax_cross_entropy
 from .moe import apply_moe, init_moe
 from .rwkv import (apply_channel_mix, apply_time_mix, init_channel_mix,
@@ -25,7 +26,8 @@ from .ssm import apply_ssm, init_ssm, init_ssm_state
 # init
 # --------------------------------------------------------------------------
 
-def init_layer(cfg, key, dtype=jnp.float32):
+def init_layer(cfg, key, dtype=jnp.float32, dense=False):
+    """One block; ``dense`` gives an MoE config's leading dense FFN."""
     d = cfg.d_model
     ones = lambda: jnp.ones((d,), dtype)
     keys = jax.random.split(key, 4)
@@ -34,11 +36,11 @@ def init_layer(cfg, key, dtype=jnp.float32):
             "ln1": ones(), "tmix": init_time_mix(cfg, keys[0], dtype),
             "ln2": ones(), "cmix": init_channel_mix(cfg, keys[1], dtype),
         }
-    p = {"ln1": ones(), "attn": init_attention(cfg, keys[0], dtype),
-         "ln2": ones()}
+    attn = init_mla if cfg.uses_mla else init_attention
+    p = {"ln1": ones(), "attn": attn(cfg, keys[0], dtype), "ln2": ones()}
     if cfg.family == "hybrid":
         p["ssm"] = init_ssm(cfg, keys[1], dtype)
-    if cfg.num_experts:
+    if cfg.num_experts and not dense:
         p["moe"] = init_moe(cfg, keys[2], dtype)
     else:
         from .common import init_swiglu
@@ -61,7 +63,12 @@ def init_model(cfg, key, dtype=jnp.float32):
     if cfg.modality == "vision":
         params["vision_proj"] = dense_init(
             keys[2], (cfg.vision_embed_dim, d), dtype)
-    layer_keys = jnp.stack(keys[4:4 + cfg.num_layers])
+    nd = cfg.first_dense_layers
+    if nd:
+        params["dense_layers"] = jax.vmap(
+            lambda k: init_layer(cfg, k, dtype, dense=True))(
+                jnp.stack(keys[4:4 + nd]))
+    layer_keys = jnp.stack(keys[4 + nd:4 + cfg.num_layers])
     params["layers"] = jax.vmap(
         lambda k: init_layer(cfg, k, dtype))(layer_keys)
     params["final_norm"] = jnp.ones((d,), dtype)
@@ -74,7 +81,8 @@ def init_model(cfg, key, dtype=jnp.float32):
 
 def apply_block(lp, x, cfg, *, mode, layer_cache, positions, pos, window,
                 q_chunk, kv_chunk):
-    """Returns (x, cache_out_or_None, aux_scalar)."""
+    """Returns (x, cache_out_or_None, aux): aux is the aux-loss scalar, or
+    for an MoE block a dict of it ("aux") and the layer's counters."""
     aux = jnp.zeros((), jnp.float32)
     cache_out = None
 
@@ -100,13 +108,20 @@ def apply_block(lp, x, cfg, *, mode, layer_cache, positions, pos, window,
         return x, cache_out, aux
 
     # --- attention (+ optional parallel SSM branch) ---
-    h_in = rms_norm(x, lp["ln1"])
-    attn_cache = None if layer_cache is None else layer_cache.get("attn")
-    attn_out, attn_cache_out = apply_attention(
-        lp["attn"], h_in, cfg, positions,
-        cache=attn_cache if mode == "decode" else None,
-        pos=pos, window=window, q_chunk=q_chunk, kv_chunk=kv_chunk,
-        return_cache=(mode == "prefill"))
+    h_in = rms_norm(x, lp["ln1"], cfg.norm_eps)
+    if cfg.uses_mla:
+        if mode == "decode":
+            raise NotImplementedError("latent attention has no decode cache")
+        attn_out = apply_mla(lp["attn"], h_in, cfg, positions,
+                             q_chunk=q_chunk, kv_chunk=kv_chunk)
+        attn_cache_out = None
+    else:
+        attn_cache = None if layer_cache is None else layer_cache.get("attn")
+        attn_out, attn_cache_out = apply_attention(
+            lp["attn"], h_in, cfg, positions,
+            cache=attn_cache if mode == "decode" else None,
+            pos=pos, window=window, q_chunk=q_chunk, kv_chunk=kv_chunk,
+            return_cache=(mode == "prefill"))
     if cfg.family == "hybrid":
         ssm_state = None if layer_cache is None else layer_cache.get("ssm")
         ssm_out, ssm_state_out = apply_ssm(
@@ -116,9 +131,10 @@ def apply_block(lp, x, cfg, *, mode, layer_cache, positions, pos, window,
     else:
         x = x + attn_out
 
-    h2 = rms_norm(x, lp["ln2"])
-    if cfg.num_experts:
-        ffn_out, aux = apply_moe(lp["moe"], h2, cfg)
+    h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
+    if "moe" in lp:
+        ffn_out, moe_aux, stats = apply_moe(lp["moe"], h2, cfg)
+        aux = {"aux": moe_aux, **stats}
     else:
         from .common import apply_swiglu
         ffn_out = apply_swiglu(lp["mlp"], h2)
@@ -162,15 +178,27 @@ def lm_logits(params, h, cfg):
 # forward
 # --------------------------------------------------------------------------
 
+def _fold_aux(auxs, stats):
+    """Sum a stack's aux losses; an MoE stack's counters go to ``stats``."""
+    if isinstance(auxs, dict):
+        stats.update({k: v for k, v in auxs.items() if k != "aux"})
+        return auxs["aux"].sum()
+    return auxs.sum()
+
+
 def forward(params, batch, cfg, *, mode="train", cache=None,
             scan_layers=True, remat=True, window=None,
-            q_chunk=1024, kv_chunk=1024, compute_logits=True):
-    """Returns (logits, new_cache, aux).
+            q_chunk=1024, kv_chunk=1024, compute_logits=True,
+            with_stats=False):
+    """Returns (logits, new_cache, aux), and the MoE layers' counters
+    (per layer, stacked) after them with ``with_stats``.
 
     batch: {"tokens": (B,S) or (B,S,C)[, "patch_embeds", "pos"]}.
     mode: train | prefill | decode.  decode consumes+updates ``cache``.
     window: sliding window (None -> cfg default: hybrid archs train with
     their configured SWA window; others full attention).
+    Stacks run in order: ``params["dense_layers"]`` (an MoE config's
+    leading dense layers) where present, then ``params["layers"]``.
     """
     if window is None:
         window = cfg.sliding_window if cfg.family == "hybrid" else 0
@@ -188,70 +216,89 @@ def forward(params, batch, cfg, *, mode="train", cache=None,
                     pos=pos, window=window, q_chunk=q_chunk,
                     kv_chunk=kv_chunk)
 
-    layer_caches = None if cache is None else cache["layers"]
+    stacks = [n for n in ("dense_layers", "layers") if n in params]
+    auxes, stats = [], {}
+    new_cache = None
     if scan_layers:
-        if mode == "train":
-            def body(h, lp):
-                fn = (jax.checkpoint(lambda h_, lp_: block(
-                    lp_, h_, layer_cache=None)[::2]) if remat
-                    else (lambda h_, lp_: block(lp_, h_, layer_cache=None)[::2]))
-                h, aux = fn(h, lp)
-                # sequence parallelism between blocks: the scan-carry
-                # activation stash shards its seq dim over "model" (Megatron
-                # SP) — a no-op without an ambient mesh.  The batch dim is
-                # UNCONSTRAINED: under the RANL vmap-over-workers it is the
-                # per-worker batch (worker axis carries "data" instead).
-                from .sharding import UNCONSTRAINED
-                h = shard_hint(h, (UNCONSTRAINED, "model", None))
-                return h, aux
-            x, auxs = jax.lax.scan(body, x, params["layers"])
-            new_cache, aux = None, auxs.sum()
-        elif mode == "prefill":
-            def body(h, lp):
-                h, c, aux = block(lp, h, layer_cache=None)
-                return h, (c, aux)
-            x, (caches, auxs) = jax.lax.scan(body, x, params["layers"])
-            new_cache, aux = {"layers": caches}, auxs.sum()
-        else:  # decode
-            def body(h, lp_cache):
-                lp, lc = lp_cache
-                h, c, aux = block(lp, h, layer_cache=lc)
-                return h, (c, aux)
-            x, (caches, auxs) = jax.lax.scan(
-                body, x, (params["layers"], layer_caches))
-            new_cache, aux = {"layers": caches}, auxs.sum()
+        for name in stacks:
+            layers = params[name]
+            if mode == "train":
+                def body(h, lp):
+                    fn = (jax.checkpoint(lambda h_, lp_: block(
+                        lp_, h_, layer_cache=None)[::2]) if remat
+                        else (lambda h_, lp_: block(
+                            lp_, h_, layer_cache=None)[::2]))
+                    h, aux = fn(h, lp)
+                    # sequence parallelism between blocks: the scan-carry
+                    # activation stash shards its seq dim over "model"
+                    # (Megatron SP) — a no-op without an ambient mesh.  The
+                    # batch dim is UNCONSTRAINED: under the RANL
+                    # vmap-over-workers it is the per-worker batch (worker
+                    # axis carries "data" instead).
+                    from .sharding import UNCONSTRAINED
+                    h = shard_hint(h, (UNCONSTRAINED, "model", None))
+                    return h, aux
+                x, auxs = jax.lax.scan(body, x, layers)
+            elif mode == "prefill":
+                def body(h, lp):
+                    h, c, aux = block(lp, h, layer_cache=None)
+                    return h, (c, aux)
+                x, (caches, auxs) = jax.lax.scan(body, x, layers)
+                new_cache = dict(new_cache or {}, **{name: caches})
+            else:  # decode
+                def body(h, lp_cache):
+                    lp, lc = lp_cache
+                    h, c, aux = block(lp, h, layer_cache=lc)
+                    return h, (c, aux)
+                x, (caches, auxs) = jax.lax.scan(
+                    body, x, (layers, cache[name]))
+                new_cache = dict(new_cache or {}, **{name: caches})
+            auxes.append(_fold_aux(auxs, stats))
     else:
-        aux = jnp.zeros((), jnp.float32)
-        cache_outs = []
-        for i in range(cfg.num_layers):
-            lp = jax.tree.map(lambda a: a[i], params["layers"])
-            lc = (None if layer_caches is None
-                  else jax.tree.map(lambda a: a[i], layer_caches))
-            x, c, a = block(lp, x, layer_cache=lc)
-            aux = aux + a
-            if c is not None:
-                cache_outs.append(c)
-        new_cache = None
+        cache_outs = {}
+        for name in stacks:
+            layers = params[name]
+            for i in range(jax.tree.leaves(layers)[0].shape[0]):
+                lp = jax.tree.map(lambda a: a[i], layers)
+                lc = (None if cache is None
+                      else jax.tree.map(lambda a: a[i], cache[name]))
+                x, c, a = block(lp, x, layer_cache=lc)
+                if isinstance(a, dict):
+                    for k, v in a.items():
+                        if k != "aux":
+                            stats.setdefault(k, []).append(v)
+                    a = a["aux"]
+                auxes.append(a)
+                if c is not None:
+                    cache_outs.setdefault(name, []).append(c)
+        stats = {k: jnp.stack(v) for k, v in stats.items()}
         if cache_outs:
-            new_cache = {"layers": jax.tree.map(
-                lambda *xs: jnp.stack(xs), *cache_outs)}
+            new_cache = {name: jax.tree.map(lambda *xs: jnp.stack(xs), *cs)
+                         for name, cs in cache_outs.items()}
 
-    x = rms_norm(x, params["final_norm"])
-    if not compute_logits:
-        return x, new_cache, aux
-    logits = lm_logits(params, x, cfg)
-    return logits, new_cache, aux
+    aux = (sum(auxes[1:], auxes[0]) if auxes
+           else jnp.zeros((), jnp.float32))
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    out = x if not compute_logits else lm_logits(params, x, cfg)
+    if with_stats:
+        return out, new_cache, aux, stats
+    return out, new_cache, aux
 
 
-def lm_loss(params, batch, cfg, *, loss_chunk=1024, **fwd_kwargs):
+def lm_loss(params, batch, cfg, *, loss_chunk=1024, with_stats=False,
+            **fwd_kwargs):
     """Next-token loss with *chunked* cross-entropy: logits are produced
     (and re-produced in the backward pass via checkpoint) one sequence chunk
     at a time, so the (B, S, vocab) tensor never materializes — at 151936
     vocab that is the difference between a multi-GiB spike and ~chunk/S of
     it.  Chunks are a Python loop (straight-line HLO) so cost analysis
-    counts every FLOP."""
-    h, _, aux = forward(params, batch, cfg, mode="train",
-                        compute_logits=False, **fwd_kwargs)
+    counts every FLOP.
+
+    ``with_stats`` returns (loss, counters): the MoE layers' routed rows
+    (``moe_counters``), for ``value_and_grad(..., has_aux=True)``."""
+    h, _, aux, stats = forward(params, batch, cfg, mode="train",
+                               compute_logits=False, with_stats=True,
+                               **fwd_kwargs)
     labels = batch["labels"]
     B, S = h.shape[:2]
     chunk = min(loss_chunk, S)
@@ -270,7 +317,22 @@ def lm_loss(params, batch, cfg, *, loss_chunk=1024, **fwd_kwargs):
         sl = slice(i * chunk, min((i + 1) * chunk, S))
         total = total + chunk_loss(h[:, sl], labels[:, sl])
         denom += labels[:, sl].size
-    return total / denom + aux
+    loss = total / denom + aux
+    return (loss, moe_counters(stats)) if with_stats else loss
+
+
+def moe_counters(stats):
+    """Per-step routing counters from the MoE stack's per-layer ones:
+    ``held_rows`` (rows the held experts computed), ``absent_rows``
+    (routes to experts not held here), ``dropped_rows`` and
+    ``expert_rows`` (layers, held experts).  Empty without MoE layers."""
+    if not stats:
+        return {}
+    rows = stats["expert_rows"]
+    return {"held_rows": jnp.sum(rows), "absent_rows":
+            jnp.sum(stats["absent_rows"]),
+            "dropped_rows": jnp.sum(stats["dropped_rows"]),
+            "expert_rows": rows}
 
 
 # --------------------------------------------------------------------------

@@ -76,40 +76,62 @@ class RanlLLMConfig:
 # region layout over a params pytree
 # --------------------------------------------------------------------------
 
-def _is_layer_path(path) -> bool:
-    return any(getattr(p, "key", None) == "layers" for p in path)
+#: stacked per-layer subtrees, in forward order: an MoE config's leading
+#: dense layers, then the main stack
+LAYER_STACKS = ("dense_layers", "layers")
+
+
+def _layer_stack(path):
+    for p in path:
+        if getattr(p, "key", None) in LAYER_STACKS:
+            return p.key
+    return None
 
 
 def region_layout(params):
     """Assign region ids: stacked layer leaves get one region per layer
-    (shared layer id across leaves), glue leaves one region each.
+    (shared layer id across the leaves of a stack), glue leaves one region
+    each.  Stacks take consecutive layer regions in forward order
+    (``LAYER_STACKS``): with a leading dense stack of depth D, its layers
+    are regions 0..D-1 and the main stack's follow from D.
 
     Returns (num_regions, num_layer_regions, leaf_infos) where leaf_infos is
-    a list aligned with tree_leaves: ("layer", L) or ("glue", region_id).
+    a list aligned with tree_leaves: ("layer", (first_region, depth)) or
+    ("glue", region_id).
 
-    Every stacked layer leaf must agree on ``leaf.shape[0]`` — layer q of
-    one leaf and layer q of another share a region id, so a mismatched
-    depth would silently assign masks to the wrong layers.
+    Every stacked leaf of one stack must agree on ``leaf.shape[0]`` —
+    layer q of one leaf and layer q of another share a region id, so a
+    mismatched depth would silently assign masks to the wrong layers.
     """
     leaves = jax.tree_util.tree_leaves_with_path(params)
     depths = {}
     for path, leaf in leaves:
-        if _is_layer_path(path):
-            depths[jax.tree_util.keystr(path)] = leaf.shape[0]
-    sizes = sorted(set(depths.values()))
-    if len(sizes) > 1:
-        detail = ", ".join(f"{k}={v}" for k, v in sorted(depths.items()))
-        raise ValueError(
-            "region_layout: stacked layer leaves disagree on the leading "
-            f"(num_layers) dim {sizes} — region ids would mis-align "
-            f"across leaves ({detail}). Stack every per-layer tensor to "
-            "the same depth, or move the odd leaf out of 'layers'.")
-    num_layers = sizes[0] if sizes else 0
+        stack = _layer_stack(path)
+        if stack is not None:
+            depths.setdefault(stack, {})[jax.tree_util.keystr(path)] = \
+                leaf.shape[0]
+    first, num_layers = {}, 0
+    for stack in LAYER_STACKS:
+        if stack not in depths:
+            continue
+        sizes = sorted(set(depths[stack].values()))
+        if len(sizes) > 1:
+            detail = ", ".join(f"{k}={v}"
+                               for k, v in sorted(depths[stack].items()))
+            raise ValueError(
+                f"region_layout: stacked leaves of {stack!r} disagree on "
+                f"the leading (num_layers) dim {sizes} — region ids would "
+                f"mis-align across leaves ({detail}). Stack every "
+                "per-layer tensor to the same depth, or move the odd leaf "
+                "out of the stack.")
+        first[stack] = num_layers
+        num_layers += sizes[0]
     infos = []
     next_glue = num_layers
     for path, leaf in leaves:
-        if _is_layer_path(path):
-            infos.append(("layer", leaf.shape[0]))
+        stack = _layer_stack(path)
+        if stack is not None:
+            infos.append(("layer", (first[stack], leaf.shape[0])))
         else:
             infos.append(("glue", next_glue))
             next_glue += 1
@@ -119,8 +141,8 @@ def region_layout(params):
 def region_param_counts(params):
     """(Q,) float32 parameter count per region.
 
-    Region q < num_layers: summed per-layer slice sizes of every stacked
-    layer leaf; glue regions: the whole leaf.  This is the per-region
+    Layer regions: summed per-layer slice sizes of the stacked leaves that
+    hold that layer; glue regions: the whole leaf.  This is the per-region
     "work" unit the heterogeneity cost models price when the closed-loop
     controller drives the deep-net path (``launch.train --scenario``).
     """
@@ -128,8 +150,9 @@ def region_param_counts(params):
     counts = [0] * num_regions
     for (kind, v), leaf in zip(infos, jax.tree_util.tree_leaves(params)):
         if kind == "layer":
-            per_layer = leaf.size // leaf.shape[0]
-            for q in range(v):
+            start, depth = v
+            per_layer = leaf.size // depth
+            for q in range(start, start + depth):
                 counts[q] += per_layer
         else:
             counts[v] += leaf.size
@@ -139,13 +162,15 @@ def region_param_counts(params):
 def leaf_masks(masks, infos, protect_glue: bool):
     """masks: (N, Q) bool -> per-leaf broadcastable masks list.
 
-    Layer leaves get masks[:, :L] reshaped (N, L, 1, ...); glue leaves get
-    masks[:, q] (or all-True when protected) reshaped (N, 1, ...).
+    Layer leaves get their stack's masks[:, first:first + L], reshaped
+    (N, L, 1, ...) where used; glue leaves get masks[:, q] (or all-True
+    when protected) reshaped (N, 1, ...).
     """
     out = []
     for kind, v in infos:
         if kind == "layer":
-            out.append(masks[:, :v])
+            start, depth = v
+            out.append(masks[:, start:start + depth])
         else:
             m = (jnp.ones_like(masks[:, v]) if protect_glue
                  else masks[:, v])
@@ -240,17 +265,26 @@ def per_worker_grads(loss_fn, params, batch, num_workers: int, *,
                      mesh=None):
     """vmap(value_and_grad) over the worker axis. batch leaves (B, ...).
 
+    A ``loss_fn`` whose ``has_aux`` attribute is true returns (loss,
+    counters); its per-worker values are then (losses, counters), as
+    ``value_and_grad(..., has_aux=True)`` gives them.
+
     With ``mesh``, the split (num_workers, B/num_workers, ...) batch is
     sharding-constrained worker-axis-over-data so pjit partitions the
     per-worker gradient evaluations across devices (real data parallelism,
-    not emulation).
+    not emulation); the vmap names the data axes (``spmd_axis_name``), so
+    sharding constraints and ``shard_map`` regions inside the loss keep
+    the worker axis on them.
     """
     wb = split_batch(batch, num_workers)
+    spmd = None
     if mesh is not None:
         wb = _shard_worker_axis(wb, mesh, num_workers)
-    losses, grads = jax.vmap(
-        lambda b: jax.value_and_grad(loss_fn)(params, b))(wb)
-    return losses, grads
+        spmd = _data_axes(mesh) or None
+    has_aux = getattr(loss_fn, "has_aux", False)
+    return jax.vmap(
+        lambda b: jax.value_and_grad(loss_fn, has_aux=has_aux)(params, b),
+        spmd_axis_name=spmd)(wb)
 
 
 def quantize_memory(G):
@@ -335,8 +369,10 @@ def train_step(params, state, batch, rng, *, loss_fn, cfg: RanlLLMConfig,
             pspecs["state"] = ranl_state_pspecs(
                 params, model_shards=mesh.shape.get("model", 1))
         batch = _apply_pspecs(batch, pspecs["batch"], mesh)
-    losses, G = per_worker_grads(loss_fn, params, batch, cfg.num_workers,
-                                 mesh=mesh)
+    value, G = per_worker_grads(loss_fn, params, batch, cfg.num_workers,
+                                mesh=mesh)
+    losses, counters = value if getattr(loss_fn, "has_aux", False) \
+        else (value, {})
     if mesh is not None:
         G = _apply_pspecs(G, pspecs["state"]["memory"], mesh)
 
@@ -402,4 +438,14 @@ def train_step(params, state, batch, rng, *, loss_fn, cfg: RanlLLMConfig,
     metrics = {"loss": losses.mean(), "grad_norm": gnorm,
                "coverage": coverage,
                "uplink_frac": masks.mean()}
+    if counters:
+        # routing over all workers: rows the held experts computed, routes
+        # to experts held elsewhere, rows dropped (0: dropless), and the
+        # busiest held expert's rows over the mean (layers x experts)
+        rows = counters["expert_rows"].sum(axis=0)
+        metrics.update(
+            held_rows=counters["held_rows"].sum(),
+            absent_rows=counters["absent_rows"].sum(),
+            dropped_rows=counters["dropped_rows"].sum(),
+            load_max_ratio=rows.max() / jnp.maximum(rows.mean(), 1e-9))
     return new_params, new_state, metrics

@@ -42,7 +42,8 @@ def test_arch_smoke_forward_and_train_step(arch):
     assert np.isfinite(gnorm) and gnorm > 0
 
 
-@pytest.mark.parametrize("arch", ALL_ARCHS)
+@pytest.mark.parametrize("arch", [a for a in ALL_ARCHS
+                                  if not get_config(a).uses_mla])
 def test_arch_decode_step(arch):
     cfg = _smoke(arch)
     params = init_model(cfg, KEY)
@@ -64,10 +65,6 @@ def test_prefill_decode_matches_full_forward(arch):
     """Teacher-forced prefill+decode must reproduce the train-mode logits
     (the serving path is a faithful incremental evaluation)."""
     cfg = _smoke(arch)
-    if cfg.num_experts:
-        # capacity truncation is batch-composition-dependent by design;
-        # disable drops so incremental == full evaluation is exact
-        cfg = dataclasses.replace(cfg, capacity_factor=8.0)
     params = init_model(cfg, KEY)
     T, Tp = 12, 8
     batch = make_batch(cfg, KEY, batch=2, seq=T, kind="train")
@@ -118,14 +115,13 @@ def test_sliding_window_decode_matches_windowed_forward():
 
 
 def test_moe_dispatch_matches_dense_oracle():
-    """Sort-based capacity dispatch == explicit per-token expert compute
-    (capacity high enough that nothing drops)."""
+    """Sorted dropless dispatch == explicit per-token expert compute."""
     from repro.models.moe import apply_moe, init_moe
-    cfg = dataclasses.replace(_smoke("phi3.5-moe-42b-a6.6b"),
-                              capacity_factor=8.0)
+    cfg = _smoke("phi3.5-moe-42b-a6.6b")
     p = init_moe(cfg, KEY)
     x = jax.random.normal(jax.random.fold_in(KEY, 1), (2, 16, cfg.d_model))
-    out, aux = apply_moe(p, x, cfg)
+    out, aux, stats = apply_moe(p, x, cfg)
+    assert int(stats["dropped_rows"]) == 0
 
     xf = x.reshape(-1, cfg.d_model)
     logits = xf @ p["router"]

@@ -15,7 +15,7 @@ from repro.data import make_batch, token_stream
 
 def test_config_registry_complete():
     archs = list_configs()
-    assert len(archs) == 10
+    assert len(archs) == 11
     families = {get_config(a).family for a in archs}
     assert families == {"dense", "moe", "ssm", "hybrid", "vlm", "audio"}
     assert set(INPUT_SHAPES) == {"train_4k", "prefill_32k", "decode_32k",

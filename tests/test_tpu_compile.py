@@ -96,3 +96,50 @@ def test_logistic_grads_compiles_for_v5e(one_chip, shape):
     x_shape = f"f32[{N},{n},{d}]"
     assert not [line for line in hlo.splitlines()
                 if " copy(" in line and x_shape in line]
+
+
+def test_sharded_moonlight_step_compiles_for_v5e_2x2(topo):
+    """The RANL step of a small Moonlight (latent attention, 4 of 8
+    experts held, split over the model axis) on a data 2 x model 2 mesh
+    of the described chips: the expert layer's grouped matmuls compile to
+    the chip's ragged-dot kernels inside the shard_map."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config, smoke_variant
+    from repro.launch.shard import (params_pspecs, ranl_state_pspecs,
+                                    to_shardings)
+    from repro.launch.train import build_loss
+    from repro.models import init_model
+    from repro.models.sharding import use_mesh
+    from repro.optim import RanlLLMConfig, init_state, train_step
+
+    cfg = dataclasses.replace(smoke_variant(get_config("moonlight-16b-a3b")),
+                              num_experts=8, experts_per_token=3,
+                              experts_held=4, num_layers=3)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("data", "model"))
+    loss_fn = build_loss(cfg, q_chunk=64, kv_chunk=64)
+    rcfg = RanlLLMConfig(num_workers=2)
+
+    def placed(tree, specs):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, to_shardings(specs, mesh))
+
+    params = jax.eval_shape(partial(init_model, cfg), jax.random.PRNGKey(0))
+    rep = NamedSharding(mesh, P())
+    batch = {k: jax.ShapeDtypeStruct((4, 128), jnp.int32, sharding=rep)
+             for k in ("tokens", "labels")}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+    state = jax.eval_shape(partial(init_state, loss_fn=loss_fn, cfg=rcfg),
+                           params, batch=batch, key=key)
+    state = placed(state, ranl_state_pspecs(params, model_shards=2))
+    params = placed(params, params_pspecs(params, model_shards=2))
+    with use_mesh(mesh):
+        hlo = jax.jit(partial(train_step, loss_fn=loss_fn, cfg=rcfg,
+                              mesh=mesh)).lower(params, state, batch, key) \
+            .compile().as_text()
+    assert "tpu_custom_call" in hlo and "ragged-dot" in hlo
+    assert "all-reduce" in hlo
