@@ -13,11 +13,14 @@ causal-attention FLOPs.
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .common import apply_rope, dense_init, rms_norm
+from .sharding import current_mesh
 
 NEG_INF = -1e30
 
@@ -180,6 +183,10 @@ def apply_mla(params, x, cfg, positions, *, q_chunk: int = 1024,
     q = x W_q gives H heads of (nope + rope) dims; [c, k_rope] = x W_kva;
     [k_nope, v] = RMSNorm(c) W_kvb per head; rotary on q_rope and on the
     one k_rope, which every head shares; softmax at (nope + rope)^-1/2.
+
+    Under a mesh whose ``model`` axis divides the heads, the attention core
+    runs inside ``shard_map`` on each device's heads: every head's scores
+    stay on the device that projected it, forward and backward.
     """
     B, S, _ = x.shape
     H, r = cfg.num_heads, cfg.kv_lora_rank
@@ -195,9 +202,16 @@ def apply_mla(params, x, cfg, positions, *, q_chunk: int = 1024,
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, H, rope))],
             axis=-1)
-        out = blocked_attention(q, k, kv[..., nope:], positions, positions,
-                                q_chunk=q_chunk, kv_chunk=kv_chunk,
-                                static_positions=True)
+        attend = partial(blocked_attention, q_chunk=q_chunk,
+                         kv_chunk=kv_chunk, static_positions=True)
+        mesh = current_mesh()
+        shards = mesh.shape.get("model", 1) if mesh is not None else 1
+        if shards > 1 and H % shards == 0:
+            hs = P(None, None, "model", None)
+            attend = jax.shard_map(attend, mesh=mesh,
+                                   in_specs=(hs, hs, hs, P(), P()),
+                                   out_specs=hs, check_vma=False)
+        out = attend(q, k, kv[..., nope:], positions, positions)
         return out.reshape(B, S, H * hv) @ params["wo"]
 
 

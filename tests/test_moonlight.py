@@ -328,3 +328,68 @@ def test_model_axis_shares_add_up_to_the_whole_layer():
     assert res["shares_sum_vs_whole"] < BLOCK_RTOL, res
     assert res["shared_twice_vs_whole"] > 10 * BLOCK_RTOL, res
     assert res["dropped"] == 0 and res["rows"] == 32 * 3, res
+
+
+_HEADS = r"""
+import os
+os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'
+import dataclasses, json
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import get_config, smoke_variant
+from repro.launch.mesh import make_mesh
+from repro.models.attention import apply_mla, init_mla
+from repro.models.sharding import use_mesh
+mesh = make_mesh((2, 2), ('data', 'model'))
+pos = jnp.broadcast_to(jnp.arange(24), (1, 24))
+res = {}
+for heads in (4, 3):
+    cfg = dataclasses.replace(smoke_variant(get_config('moonlight-16b-a3b')),
+                              num_heads=heads, num_kv_heads=heads)
+    key = jax.random.PRNGKey(heads)
+    p = init_mla(cfg, key)
+    # two workers of one sequence each, vmapped over "data" as RANL's are
+    x = jax.random.normal(jax.random.fold_in(key, 1), (2, 1, 24, cfg.d_model))
+    probe = jax.random.normal(jax.random.fold_in(key, 2), x.shape)
+
+    def value(p, x, spmd):
+        out = jax.vmap(lambda x: apply_mla(p, x, cfg, pos, q_chunk=8,
+                                           kv_chunk=8),
+                       spmd_axis_name=spmd)(x)
+        return jnp.sum(out * probe)
+
+    grad = jax.value_and_grad(value, argnums=(0, 1))
+    got = {False: jax.jit(grad, static_argnums=2)(p, x, None)}
+    with use_mesh(mesh):
+        got[True] = jax.jit(grad, static_argnums=2)(p, x, 'data')
+        jaxpr = str(jax.make_jaxpr(grad, static_argnums=2)(p, x, 'data'))
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    (v0, (gp0, gx0)), (v1, (gp1, gx1)) = got[False], got[True]
+    res[heads] = {'value': abs(float(v1 - v0)) / abs(float(v0)),
+                  'x': rel(gx1, gx0),
+                  'weights': {k: rel(gp1[k], gp0[k]) for k in gp0},
+                  'shard_map': 'shard_map' in jaxpr}
+print(json.dumps(res))
+"""
+
+
+def test_head_local_attention_matches_the_unsharded_layer():
+    """Under a data 2 x model 2 mesh, with two workers vmapped over
+    ``data``, latent attention computes each device's heads inside
+    ``shard_map``: its output and the gradients of a scalar of it (w.r.t.
+    x and every weight) match the call without a mesh.  Three heads do
+    not split over two model shards and take the unsharded path, with the
+    same numbers."""
+    env = dict(os.environ)
+    here = os.path.dirname(os.path.abspath(__file__))
+    env["PYTHONPATH"] = os.path.join(here, "..", "src")
+    out = subprocess.run([sys.executable, "-c", _HEADS], env=env,
+                         capture_output=True, text=True, timeout=240)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    for heads, split in (("4", True), ("3", False)):
+        r = res[heads]
+        assert r["shard_map"] is split, res
+        assert r["value"] < BLOCK_RTOL and r["x"] < BLOCK_RTOL, res
+        assert set(r["weights"]) == {"wq", "wkv_a", "kv_norm", "wkv_b",
+                                     "wo"}, res
+        assert max(r["weights"].values()) < BLOCK_RTOL, res

@@ -8,10 +8,12 @@ pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
 
+import re
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -98,14 +100,13 @@ def test_logistic_grads_compiles_for_v5e(one_chip, shape):
                 if " copy(" in line and x_shape in line]
 
 
-def test_sharded_moonlight_step_compiles_for_v5e_2x2(topo):
-    """The RANL step of a small Moonlight (latent attention, 4 of 8
-    experts held, split over the model axis) on a data 2 x model 2 mesh
-    of the described chips: the expert layer's grouped matmuls compile to
-    the chip's ragged-dot kernels inside the shard_map."""
+def _moonlight_step_hlo(topo, shape, chunk):
+    """The compiled RANL step of a small Moonlight (latent attention, 4 of
+    8 experts held, split over the model axis; 2 workers) on a data 2 x
+    model 2 mesh of the described chips, at a (rows, tokens) batch
+    ``shape`` and attention blocks of ``chunk``."""
     import dataclasses
 
-    import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.configs import get_config, smoke_variant
@@ -120,7 +121,7 @@ def test_sharded_moonlight_step_compiles_for_v5e_2x2(topo):
                               num_experts=8, experts_per_token=3,
                               experts_held=4, num_layers=3)
     mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("data", "model"))
-    loss_fn = build_loss(cfg, q_chunk=64, kv_chunk=64)
+    loss_fn = build_loss(cfg, q_chunk=chunk, kv_chunk=chunk)
     rcfg = RanlLLMConfig(num_workers=2)
 
     def placed(tree, specs):
@@ -130,7 +131,7 @@ def test_sharded_moonlight_step_compiles_for_v5e_2x2(topo):
 
     params = jax.eval_shape(partial(init_model, cfg), jax.random.PRNGKey(0))
     rep = NamedSharding(mesh, P())
-    batch = {k: jax.ShapeDtypeStruct((4, 128), jnp.int32, sharding=rep)
+    batch = {k: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
              for k in ("tokens", "labels")}
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
     state = jax.eval_shape(partial(init_state, loss_fn=loss_fn, cfg=rcfg),
@@ -138,8 +139,41 @@ def test_sharded_moonlight_step_compiles_for_v5e_2x2(topo):
     state = placed(state, ranl_state_pspecs(params, model_shards=2))
     params = placed(params, params_pspecs(params, model_shards=2))
     with use_mesh(mesh):
-        hlo = jax.jit(partial(train_step, loss_fn=loss_fn, cfg=rcfg,
-                              mesh=mesh)).lower(params, state, batch, key) \
+        return jax.jit(partial(train_step, loss_fn=loss_fn, cfg=rcfg,
+                               mesh=mesh)).lower(params, state, batch, key) \
             .compile().as_text()
+
+
+def test_sharded_moonlight_step_compiles_for_v5e_2x2(topo):
+    """The expert layer's grouped matmuls compile to the chip's ragged-dot
+    kernels inside the shard_map."""
+    hlo = _moonlight_step_hlo(topo, (4, 128), 64)
     assert "tpu_custom_call" in hlo and "ragged-dot" in hlo
     assert "all-reduce" in hlo
+
+
+_BYTES = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1,
+          "u8": 1, "pred": 1}
+
+
+def test_latent_attention_moves_no_score_block_for_v5e_2x2(topo):
+    """Latent attention computes each device's heads where they were
+    projected: no all-to-all in its scope moves as much as half of one
+    per-device score block, forward or backward.  The blocks (512 x 512)
+    are larger than a device's q, k and v, so a re-sharded score block
+    would stand out."""
+    hlo = _moonlight_step_hlo(topo, (4, 1024), 512)
+    # one worker's 2 rows x 2 of the 4 heads x a 512 x 512 f32 block
+    block = 2 * 2 * 512 * 512 * 4
+    moved = []
+    for line in hlo.splitlines():
+        op = re.search(r"=\s*(.*?)\s+all-to-all(?:-start)?\(", line)
+        if op is None or "/mla/" not in line:
+            continue
+        moved.append(sum(
+            _BYTES[dtype] * int(np.prod([int(d) for d in dims.split(",")
+                                         if d]))
+            for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                          op.group(1))))
+    assert "/mla/" in hlo, "latent attention's scope left the metadata"
+    assert max(moved, default=0) < block / 2, sorted(moved)[-5:]
