@@ -13,7 +13,6 @@ The supported engine surface is ``repro.run`` / ``repro.lower`` with a
 from . import obs  # noqa: F401
 from .api import ENGINES, lower, run, trace  # noqa: F401
 from .core.options import (  # noqa: F401
-    EngineDeprecationWarning,
     QuorumSpec,
     RanlOptions,
 )

@@ -1,10 +1,9 @@
 """``repro.run`` / ``repro.lower`` — one dispatcher over the engine family.
 
 Every engine (the compiled ``lax.scan`` loop, the vmapped multi-seed
-batch, the 1-D worker-sharded and 2-D dimension-sharded ``shard_map``
-programs, and the eager host-loop reference oracle) runs the same
-Algorithm 1; historically each had its own entrypoint with ~14 drifting
-kwargs.  This module is the replacement surface:
+batch, the worker-sharded and dimension-sharded placements of the one
+``shard_map`` round loop, and the eager host-loop reference oracle) runs
+the same Algorithm 1.  This module is their one surface:
 
     import repro
     result = repro.run(problem, key, engine="sharded",
@@ -45,16 +44,14 @@ Engine-compatibility rules enforced here, before any trace:
 
 from __future__ import annotations
 
-from .core.options import EngineDeprecationWarning, RanlOptions  # noqa: F401
+from .core.options import RanlOptions  # noqa: F401
 from .core.ranl import (
     RanlResult,  # noqa: F401
     _lower_sharded,
-    _lower_sharded2d,
     _run_batch,
     _run_reference,
     _run_scan,
     _run_sharded,
-    _run_sharded2d,
     trace_ranl,
 )
 from .obs.trace import span
@@ -155,14 +152,14 @@ def run(problem, key, *, engine: str = "scan",
                                 cost=cost)
         elif engine == "sharded":
             result = _run_sharded(problem, key, opts, mesh=mesh,
-                                  axis_name=axis_name, pod_axis=pod_axis,
+                                  data_axis=axis_name, pod_axis=pod_axis,
                                   controller=controller, cost=cost)
         elif engine == "sharded2d":
-            result = _run_sharded2d(problem, key, opts, mesh=mesh,
-                                    data_axis=data_axis,
-                                    model_axis=model_axis,
-                                    pod_axis=pod_axis,
-                                    controller=controller, cost=cost)
+            result = _run_sharded(problem, key, opts, mesh=mesh,
+                                  data_axis=data_axis,
+                                  model_axis=model_axis,
+                                  pod_axis=pod_axis,
+                                  controller=controller, cost=cost)
         else:
             result = _run_reference(problem, key, opts,
                                     controller=controller, cost=cost)
@@ -197,13 +194,12 @@ def lower(problem, key, *, engine: str = "sharded",
     with span("ranl.lower", engine=engine):
         if engine == "sharded":
             return _lower_sharded(problem, key, opts, mesh=mesh,
-                                  axis_name=axis_name, pod_axis=pod_axis,
+                                  data_axis=axis_name, pod_axis=pod_axis,
                                   controller=controller, cost=cost)
-        return _lower_sharded2d(problem, key, opts, mesh=mesh,
-                                data_axis=data_axis,
-                                model_axis=model_axis,
-                                pod_axis=pod_axis, controller=controller,
-                                cost=cost)
+        return _lower_sharded(problem, key, opts, mesh=mesh,
+                              data_axis=data_axis, model_axis=model_axis,
+                              pod_axis=pod_axis, controller=controller,
+                              cost=cost)
 
 
 def trace(problem, key, *, engine: str = "scan",

@@ -41,19 +41,11 @@ from .masks import (  # noqa: F401
     staleness_weights,
 )
 from .options import (  # noqa: F401
-    EngineDeprecationWarning,
     QuorumSpec,
     RanlOptions,
 )
 from .ranl import (  # noqa: F401
     RanlResult,
-    lower_ranl_sharded,
-    lower_ranl_sharded2d,
-    run_ranl,
-    run_ranl_batch,
-    run_ranl_reference,
-    run_ranl_sharded,
-    run_ranl_sharded2d,
     trace_ranl,
 )
 from .regions import contiguous_regions, expand_mask, region_sizes  # noqa: F401

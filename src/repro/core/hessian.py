@@ -335,8 +335,8 @@ def running_mean_hessian(problem, x, hkeys):
 
     The left-to-right Python-loop fold (NOT lax.scan) is load-bearing:
     every engine and baseline that promises 'identical init phase' parity
-    on a fixed key — ``run_ranl`` vs ``run_ranl_reference``, the newton
-    baselines — must accumulate in this exact order, eagerly, because
+    on a fixed key — ``repro.run`` with ``engine="scan"`` vs
+    ``engine="reference"``, the newton baselines — must accumulate in this exact order, eagerly, because
     tracing the per-row noise transform under scan shifts it by ~1 ulp
     and the κ-conditioned solve amplifies that past the 1e-6 pins.  This
     is the single shared definition; do not re-inline it.

@@ -1,14 +1,8 @@
 """`RanlOptions` — the one frozen, hashable options record every engine takes.
 
-The five historical entrypoints (``run_ranl``, ``run_ranl_batch``,
-``run_ranl_sharded``, ``run_ranl_sharded2d``, ``run_ranl_reference``) each
-copied ~14 kwargs and drifted: ``projection`` was missing from the 2-D
-engine, ``record_every`` existed on two of the five (and was a no-op on
-both), ``use_kernel`` was absent from the 1-D sharded engine.  The
-dispatcher ``repro.run(problem, key, engine=..., options=RanlOptions(...))``
-replaces all of them; this module is where the kwarg explosion stops —
-new knobs (the semi-synchronous quorum family below) land here and ONLY
-here.
+The dispatcher ``repro.run(problem, key, engine=...,
+options=RanlOptions(...))`` hands it to every engine; new knobs (the
+semi-synchronous quorum family below) land here and ONLY here.
 
 ``RanlOptions`` is a frozen dataclass of hashable scalars, so it can ride
 jit static args directly, and it validates at CONSTRUCTION time: a bad
@@ -27,16 +21,6 @@ from __future__ import annotations
 from dataclasses import dataclass, field, fields, replace
 
 from .masks import PolicyConfig
-
-
-class EngineDeprecationWarning(DeprecationWarning):
-    """Raised (as a warning) by the five legacy engine entrypoints.
-
-    A subclass so the repo's pytest config can run with
-    ``error::repro.core.options.EngineDeprecationWarning`` — every
-    in-repo caller must use ``repro.run``/``repro.lower`` — without
-    turning unrelated third-party DeprecationWarnings into failures.
-    """
 
 
 _CURVATURES = ("dense", "diag")

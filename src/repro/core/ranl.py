@@ -19,39 +19,37 @@ Engine layout:
   scanned body, so all rounds trace and compile once;
 * coverage / communication / τ* diagnostics ride the scan outputs instead
   of host-side Python accumulators;
-* ``run_ranl_batch`` vmaps init + rounds over seeds: many independent runs
-  in one compilation, for variance-banded convergence curves — and shards
-  the seed axis across devices when given a ``mesh``;
+* the ``batch`` engine vmaps init + rounds over seeds: many independent
+  runs in one compilation, for variance-banded convergence curves — and
+  shards the seed axis across devices when given a ``mesh``;
 * ``curvature="diag"`` swaps the dense Definition-4 eigen-projection for a
   Hutchinson diagonal estimate and dispatches each round's fused
   aggregate + projected-Newton step to the Pallas ``ranl_update`` kernel
   (interpret mode on CPU, compiled on TPU);
-* ``run_ranl_sharded`` partitions the *worker* axis across the devices of
-  a ``("data",)`` mesh via ``shard_map``: per-worker gradients and the
-  gradient memory C_i stay device-local (the paper's per-worker state),
-  and server aggregation is expressed as real collectives — a tiny
-  region-sized ``psum`` for coverage counts plus exactly ONE param-sized
-  ``psum`` per round (the single-reduction form of ``masked_aggregate``).
-  ``lower_ranl_sharded`` exposes the partitioned HLO so tests can assert
-  that communication claim on the compiled module;
-* ``run_ranl_sharded2d`` adds the *dimension* axis: a 2-D
-  ``("data", "model")`` mesh where workers shard over "data" as above and
-  the parameter dimension d shards over "model" — per-device slices of C,
-  G, hdiag and the region masks, the param all-reduce shrunk to a
-  d/n_model-float psum over only the data axis, and (dense path) the
-  replicated Cholesky replaced by a blocked right-looking factorization +
-  blocked triangular solves over row panels.  The dense INIT is sharded
-  too: the mean worker Hessian is accumulated as model-axis row panels
+* the ``sharded`` and ``sharded2d`` engines are ONE round loop under
+  ``shard_map`` (``_sharded_rounds_body``), given a placement.  The
+  *worker* axis always partitions over ``"data"``: per-worker gradients
+  and the gradient memory C_i stay device-local (the paper's per-worker
+  state), and server aggregation is expressed as real collectives — a
+  tiny region-sized ``psum`` for coverage counts plus exactly ONE
+  param-sized ``psum`` per round (the single-reduction form of
+  ``masked_aggregate``).  ``sharded2d`` adds the *dimension* axis
+  ``"model"``: per-device row slices of C, G, hdiag and the region
+  masks, the param all-reduce shrunk to a d/n_model-float psum over only
+  the data axis, and (dense path) the replicated Cholesky replaced by a
+  blocked right-looking factorization + blocked triangular solves over
+  row panels.  The 2-D dense INIT is sharded too: the mean worker
+  Hessian is accumulated as model-axis row panels
   (``worker_hessian_rows`` oracles, scan over local workers), the
-  Definition-4 projection runs as the matmul-only Newton–Schulz iteration
-  over those panels (``hessian.project_psd_ns_panels`` — no eigh, no
-  replicated buffer), and the blocked factorization + first Newton step
-  complete the phase, so with ``curvature="dense"`` NO device ever
-  materializes a d×d buffer at ANY phase — init included, proven on the
-  compiled HLO via ``hlo_analysis.max_array_bytes``.
-  ``lower_ranl_sharded2d`` exposes the partitioned HLO (the whole
-  program for dense) for the memory/communication assertions;
-* both sharded engines take ``overlap=True``: a double-buffered
+  Definition-4 projection runs as the matmul-only Newton–Schulz
+  iteration over those panels (``hessian.project_psd_ns_panels`` — no
+  eigh, no replicated buffer), and the blocked factorization + first
+  Newton step complete the phase, so with ``curvature="dense"`` NO
+  device ever materializes a d×d buffer at ANY phase — init included,
+  proven on the compiled HLO via ``hlo_analysis.max_array_bytes``.
+  ``_lower_sharded`` (``repro.lower``) exposes the partitioned HLO for
+  the memory/communication assertions;
+* the sharded loop takes ``overlap=True``: a double-buffered
   (software-pipelined) round loop in which each round's param-shard
   ``psum`` is issued and, while it is in flight, the NEXT round's
   x-independent work — mask/key sampling and its coverage-count psum —
@@ -63,8 +61,8 @@ Engine layout:
   compute on real links.
 
 For single runs the init phase executes eagerly (op-by-op, exactly the
-reference sequence) so the trajectory reproduces ``run_ranl_reference`` —
-the original host-loop driver kept below as the semantic oracle — on a
+reference sequence) so the trajectory reproduces the ``reference`` engine
+— the original host-loop driver kept below as the semantic oracle — on a
 fixed key; parity tests pin this.  ``projection="ns"`` swaps the init
 eigh for the same Newton–Schulz projection the 2-D engine shards — the
 single-device oracle the 2-D dense parity tests compare against.
@@ -75,8 +73,8 @@ wrapped in the bit-exact ``PolicyController`` shim when absent) and
 ``cost=`` (a per-worker ``CostModel``; availability dynamics filter the
 sampled masks, and the simulated per-round wall-clock / max-staleness
 traces land in ``RanlResult.round_time`` / ``.max_stale``).  Controller
-state and the telemetry ride the round loop's ``lax.scan`` carry in all
-four engines; in the sharded engines the controller runs replicated on
+state and the telemetry ride the round loop's ``lax.scan`` carry in every
+engine; in the sharded loop the controller runs replicated on
 the full (N, Q) telemetry — it adds NO collective, the coverage-count
 psum it observes is the one the aggregation already paid, so the
 one-param-sized-psum-per-round HLO invariant is preserved with
@@ -95,15 +93,13 @@ like the controller).  ``quorum=None`` compiles the historical
 synchronous computation unchanged; ``quorum=1.0`` runs the quorum code
 path but degenerates to it bit-exactly.
 
-The five historical entrypoints at the bottom of this module are
-deprecated shims over ``repro.run``/``repro.lower`` (see ``repro.api``);
-the engine internals are the ``_run_*`` functions taking ``RanlOptions``.
+The engine internals are the ``_run_*`` functions taking ``RanlOptions``;
+``repro.run``/``repro.lower`` (``repro.api``) dispatch to them.
 """
 
 from __future__ import annotations
 
 import functools
-import warnings
 from dataclasses import dataclass, replace as dc_replace
 
 import jax
@@ -120,9 +116,7 @@ from .compression import CompressionSpec, compressed_quorum_aggregate, \
 from .hessian import hutchinson_diag, project_diag, project_psd, \
     project_psd_ns, project_psd_ns_panels, running_mean_hessian, \
     solve_projected
-from .masks import PolicyConfig
-from .options import EngineDeprecationWarning, HierarchySpec, QuorumSpec, \
-    RanlOptions
+from .options import HierarchySpec, QuorumSpec, RanlOptions
 from .regions import contiguous_regions, expand_mask, region_sizes
 
 
@@ -256,8 +250,7 @@ def _round_diagnostics(covered_q, count_q, n_workers: int):
     uncovered regions to N hid them behind tau_star >= 1).
     ``min_covered_count`` maps uncovered regions to N (excluded from the
     min) — it feeds ``tau_covered``, the memory-fallback reading.  Single
-    source of truth for every engine (scan/batch, 1-D sharded, 2-D
-    sharded, reference).
+    source of truth for every engine (scan/batch, sharded, reference).
     """
     return (covered_q.mean(), count_q.min(),
             jnp.where(covered_q, count_q, n_workers).min())
@@ -274,9 +267,9 @@ def _controller_mask(controller, cost, ctrl_state, telem, kt, t,
                      num_workers: int, num_regions: int):
     """One controller step + the cost model's availability filter.
 
-    Shared by every engine (scan/batch, 1-D sharded, 2-D sharded,
-    reference).  The availability branch is STATIC (cost metadata), so a
-    cost model without dropout/churn adds no ops and no PRNG use — the
+    Shared by every engine (scan/batch, sharded, reference).  The
+    availability branch is STATIC (cost metadata), so a cost model
+    without dropout/churn adds no ops and no PRNG use — the
     PolicyController default path stays bit-identical to the historical
     ``sample_masks`` call.
     """
@@ -698,7 +691,9 @@ _batch_jit = functools.partial(
 
 
 # --------------------------------------------------------------------------
-# device-sharded engine: worker axis partitioned over a ("data",) mesh
+# sharded engines: one round loop under shard_map, given a placement — the
+# worker axis over ``data_axis`` (jointly ``(pod_axis, data_axis)`` when
+# hierarchical) and, when ``model_axis`` is set, the dimension d over it
 # --------------------------------------------------------------------------
 
 def _replicated_specs(tree):
@@ -718,21 +713,34 @@ def _worker_sharded_specs(problem, axis_name: str):
     return jax.tree.map(spec, problem)
 
 
-def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
-                         axis_name: str, num_rounds: int, num_regions: int,
-                         controller, mu: float, lr: float,
-                         curvature: str, cho_lower: bool, num_workers: int,
-                         overlap: bool, qspec: QuorumSpec | None = None,
+def _sharded_rounds_body(problem, k_loop, x1, C0, chol, hdiag, cost, *,
+                         data_axis: str, model_axis: str | None,
+                         num_rounds: int, num_regions: int, controller,
+                         mu: float, lr: float, curvature: str,
+                         use_kernel: bool, interpret: bool | None,
+                         cho_lower: bool, num_workers: int, n_data: int,
+                         n_model: int, overlap: bool,
+                         qspec: QuorumSpec | None = None,
                          comp: CompressionSpec | None = None,
                          pod_axis: str = "pod",
                          hspec: HierarchySpec | None = None):
-    """Per-device round loop (runs under ``shard_map``).
+    """Per-device round loop of both sharded engines (runs under
+    ``shard_map``; the 2-D dense program calls it inline after its sharded
+    init).
 
-    ``problem``/``C0`` arrive worker-sharded (N/n_dev local workers);
-    ``x1`` and the curvature state are replicated.  Each round issues one
-    region-sized ``psum`` (coverage counts) and ONE param-sized ``psum``
-    (the single-reduction aggregate) — the memory C never leaves the
-    device that owns its workers.
+    The placement: ``problem``/``C0`` arrive worker-sharded over
+    ``data_axis`` (N/n_data local workers).  With ``model_axis=None`` each
+    device holds all of d, and the curvature state is replicated: ``chol``
+    with ``cho_lower`` (the Cholesky factor of [H]_μ) or ``hdiag``.  With a
+    ``model_axis`` d splits too: C, the pruned gradients, ``hdiag`` and
+    the region masks are the device's p = d/n_model rows, ``chol`` is its
+    (p, d) row panel of the factor, and the dense solve is the blocked one
+    over the panels.  ``x1`` is replicated in both (the gradient oracles
+    need the full iterate).  Each round issues one region-sized psum
+    (coverage counts) and exactly ONE param-sized psum over ``data_axis``
+    (the single-reduction aggregate of p floats); the 2-D solve adds only
+    model-axis collectives.  The memory C never leaves the device that
+    owns its (worker, rows) tile.
 
     ``overlap=True`` software-pipelines the loop: round t's mask/key
     sampling and coverage-count psum move into iteration t−1's carry, so
@@ -753,7 +761,7 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
     (deadline, on-time workers, delays) is computed REPLICATED from the
     full mask and times in ``sample_round`` — x-independent, so it rides
     the overlap carry like the mask itself — and the device-local
-    ``(max_delay, d)`` late-buffer slice folds into the round's ONE
+    ``(max_delay, p)`` late-buffer tile folds into the round's ONE
     param-sized psum (each device contributes its own workers' damped
     late mass), so the quorum path adds NO collective.  ``qspec=None``
     compiles the synchronous loop unchanged.
@@ -763,46 +771,64 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
     — plus, in quorum mode, its due late-buffer row, since the late mass
     physically rides the same all-reduce on this wire — is quantized
     (int8 shared-scale / bf16) or top-k sparsified, with a per-device
-    error-feedback residual ``err`` (d,) in the scan carry.  The memory C
-    and the late buffer stay device-local and exact.  ``comp=None`` is a
-    static Python branch: the uncompressed loop compiles unchanged.
+    error-feedback residual ``err`` (p,) in the scan carry.  Top-k region
+    selection is per model shard (the residual absorbs the difference).
+    The memory C and the late buffer stay device-local and exact.
+    ``comp=None`` is a static Python branch: the uncompressed loop
+    compiles unchanged.
 
     With ``hspec`` the loop is hierarchical: workers shard JOINTLY over
-    ``(pod_axis, axis_name)``, so every in-round collective — the count
-    psum and the ONE param-sized psum — reduces over ``axis_name`` only
-    and is therefore pod-local for free (pod-local coverage counts,
-    denominators and ``C/N_p`` fallback — the same round math each pod
-    of the scan engine's ``_hier_scan_rounds`` runs).  The scan nests:
-    outer over the ``num_rounds/period`` exchange windows, inner over
-    each window's rounds, and the ONLY ``pod_axis`` collective in the
-    whole loop is the anchored-delta exchange at the window tail —
-    one d-sized psum (optionally int8/bf16-compressed with its own
-    error-feedback residual) whose HLO loop multiplier is the window
-    count E, not the round count T.  That nesting is the
-    inter-pod-bytes-shrink-by-period contract the HLO auditor proves.
+    ``(pod_axis, data_axis)``, so every in-round collective — the count
+    psum and the ONE param-sized psum — reduces over ``data_axis`` only
+    (or ``model_axis``, for the 2-D assembly) and is therefore pod-local
+    for free (pod-local coverage counts, denominators and ``C/N_p``
+    fallback — the same round math each pod of the scan engine's
+    ``_hier_scan_rounds`` runs).  The scan nests: outer over the
+    ``num_rounds/period`` exchange windows, inner over each window's
+    rounds, and the ONLY ``pod_axis`` collective in the whole loop is the
+    anchored-delta exchange at the window tail — one d-sized psum
+    (optionally int8/bf16-compressed with its own error-feedback
+    residual) whose HLO loop multiplier is the window count E, not the
+    round count T.  That nesting is the inter-pod-bytes-shrink-by-period
+    contract the HLO auditor proves.
     """
     from ..hetero.cost import pod_exchange_time, quorum_split, worker_times
     from ..hetero.controller import initial_telemetry, next_telemetry
-    N = num_workers                       # global worker count
+    N, Q = num_workers, num_regions
     d = x1.shape[0]
-    Q = num_regions
-    region_ids = contiguous_regions(d, Q)
-    sizes_q = region_sizes(region_ids, Q)
+    p = d // n_model                      # rows of d held by this device
     n_local = problem.num_workers         # workers held by this shard
-    n_dev = max(N // max(n_local, 1), 1)  # worker-axis devices in total
+    me_d = jax.lax.axis_index(data_axis)
+    split_d = model_axis is not None      # d split over model_axis
+    me_m = jax.lax.axis_index(model_axis) if split_d else 0
     hier = hspec is not None
     pods = hspec.pods if hier else 1
     n_pop = N // pods                     # workers per pod (= N when flat)
-    n_data = max(n_pop // max(n_local, 1), 1)  # data-axis devices per pod
-    n_agg = n_data if hier else n_dev     # devices joining the param psum
-    shard = jax.lax.axis_index(axis_name)
     me_pod = jax.lax.axis_index(pod_axis) if hier else 0
-    start = (me_pod * n_data + shard) * n_local if hier else shard * n_local
+    wstart = (me_pod * n_data + me_d) * n_local if hier else me_d * n_local
+    row_start = me_m * p if split_d else 0
+    region_ids = contiguous_regions(d, Q)
+    if split_d:
+        from ..kernels.region_aggregate import local_region_ids
+        region_ids_loc = local_region_ids(d, Q, row_start, p)
+        grad_local = jax.vmap(
+            lambda i, xp, k: problem.worker_grad_rows(i, xp, k, row_start, p))
+    else:
+        region_ids_loc = region_ids
+        grad_local = jax.vmap(problem.worker_grad, in_axes=(0, 0, 0))
+    sizes_q = region_sizes(region_ids, Q)           # (Q,) static
     local_ids = jnp.arange(n_local)
-    grad_pruned = jax.vmap(problem.worker_grad, in_axes=(0, 0, 0))
     hcomp = parse_compression(hspec.compression) if hier else None
     pod_wire = _pod_wire_bytes(comp, d)   # flat-on-topology charge
     hier_wire = _pod_wire_bytes(hcomp, d)
+    # the fused Pallas kernel aggregates over the workers it can see, so it
+    # is exact only when this device sees ALL workers (pure model-parallel
+    # meshes); otherwise the collective jnp form is used.  It has no
+    # late-fold form, so quorum and hierarchical runs always take the jnp
+    # path, and the ("data",) placement never takes it.
+    kernel_ok = (split_d and use_kernel and curvature == "diag"
+                 and n_data == 1 and qspec is None and comp is None
+                 and not hier)
 
     def sample_round(t, ctrl_state, telem):
         """Everything x-independent about round t: step the controller on
@@ -816,10 +842,10 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
         M_full, ctrl_state = _controller_mask(controller, cost, ctrl_state,
                                               telem, kt, t, N, Q)
         gk_full = jax.random.split(jax.random.fold_in(kt, 7), N)
-        M = jax.lax.dynamic_slice_in_dim(M_full, start, n_local)
-        gk = jax.lax.dynamic_slice_in_dim(gk_full, start, n_local)
+        M = jax.lax.dynamic_slice_in_dim(M_full, wstart, n_local)
+        gk = jax.lax.dynamic_slice_in_dim(gk_full, wstart, n_local)
         # pod-local counts under hier: same collective, per-pod values
-        count_q = jax.lax.psum(M.sum(axis=0), axis_name)
+        count_q = jax.lax.psum(M.sum(axis=0), data_axis)
         work = (M_full * sizes_q[None, :]).sum(axis=1)
         ubytes = uplink_bytes(comp, M_full, sizes_q)
         times = worker_times(cost, work, t, ubytes, overlap=overlap)
@@ -840,9 +866,9 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
                 count_disp, me_pod, 1)[0]                        # my pod's
             qinfo = (count_disp,
                      jax.lax.dynamic_slice_in_dim(on_p.reshape(N),
-                                                  start, n_local),
+                                                  wstart, n_local),
                      jax.lax.dynamic_slice_in_dim(delays_p.reshape(N),
-                                                  start, n_local),
+                                                  wstart, n_local),
                      deadline_p.max(), count_on_loc)
         else:
             deadline, on_time, delays = quorum_split(
@@ -851,19 +877,29 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
             count_on = (M_full & on_time[:, None]).sum(axis=0)
             count_disp = count_on
             qinfo = (count_on,
-                     jax.lax.dynamic_slice_in_dim(on_time, start, n_local),
-                     jax.lax.dynamic_slice_in_dim(delays, start, n_local),
+                     jax.lax.dynamic_slice_in_dim(on_time, wstart, n_local),
+                     jax.lax.dynamic_slice_in_dim(delays, wstart, n_local),
                      deadline, count_on)
         return (M, gk, count_q, work, times, qinfo, ubytes,
                 count_disp), ctrl_state
 
+    def scatter_rows(vec_loc):
+        """Assemble a replicated (d,) vector from this device's rows — one
+        model-axis psum of d floats; the identity when d is not split."""
+        if not split_d:
+            return vec_loc
+        return jax.lax.psum(
+            jax.lax.dynamic_update_slice(jnp.zeros(d, vec_loc.dtype),
+                                         vec_loc, (row_start,)), model_axis)
+
     def _psum_payload(y, err):
-        """The round's ONE param-sized all-reduce — compressed when
-        ``comp`` is set (returns the updated error-feedback residual)."""
+        """The round's ONE param-sized all-reduce over ``data_axis`` —
+        compressed when ``comp`` is set (returns the updated
+        error-feedback residual)."""
         if comp is None:
-            return jax.lax.psum(y, axis_name), err
-        return psum_compressed(comp, y, err, axis_name=axis_name,
-                               n_agg=n_agg, region_ids=region_ids,
+            return jax.lax.psum(y, data_axis), err
+        return psum_compressed(comp, y, err, axis_name=data_axis,
+                               n_agg=n_data, region_ids=region_ids_loc,
                                num_regions=Q)
 
     def round_update(x, C, err, late_buf, sampled):
@@ -876,21 +912,36 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
         needed.  Quorum mode: only on-time workers contribute fresh (over
         the FULL count, so late γ-damped arrivals reconstruct the
         synchronous mean), the device-local late buffer's due row joins
-        the same psum, and this round's late work enqueues."""
-        M, gk, count_q, work, times, qinfo, _, _ = sampled
-        Mx = expand_mask(M, region_ids)                  # (n_local, d)
-        x_pruned = jnp.where(Mx, x[None, :], 0.0)
-        G = grad_pruned(local_ids, x_pruned, gk) * Mx
-        count_x = jnp.take(count_q, region_ids)
+        the same psum, and this round's late work enqueues.
+
+        Returns (x_new, C, err, g, late_buf): the fused kernel path
+        returns the new iterate (its model-axis assembly psum issued) and
+        ``g=None``; otherwise ``x_new=None`` and ``g`` is the psum's
+        result for ``finish_step`` to consume."""
+        M, gk, count_q, qinfo = sampled[0], sampled[1], sampled[2], sampled[5]
+        Mx_full = expand_mask(M, region_ids)        # (n_local, d)
+        Mx = (expand_mask(M, region_ids_loc) if split_d
+              else Mx_full)                         # (n_local, p) own rows
+        x_pruned = jnp.where(Mx_full, x[None, :], 0.0)
+        G = grad_local(local_ids, x_pruned, gk) * Mx
+        if kernel_ok:
+            from ..kernels.region_aggregate import ranl_update
+            # all workers are local: the fused aggregate + projected-Newton
+            # kernel runs on this device's d-slice unchanged
+            x_loc = jax.lax.dynamic_slice(x, (row_start,), (p,))
+            x_loc, C = ranl_update(x_loc, hdiag, G, Mx, C, mu=mu, lr=lr,
+                                   interpret=interpret)
+            return scatter_rows(x_loc), C, err, None, late_buf
+        count_x = jnp.take(count_q, region_ids_loc)
         denom = jnp.maximum(count_x, 1).astype(G.dtype)
         if qspec is None:
-            covered_x = jnp.take(count_q > 0, region_ids)
+            covered_x = jnp.take(count_q > 0, region_ids_loc)
             contrib = jnp.where(covered_x[None, :], G / denom, C / n_pop)
             g, err = _psum_payload(contrib.sum(axis=0), err)
-            C = jnp.where(Mx, G, C)                      # device-local
-            return g, C, err, Mx, late_buf
+            C = jnp.where(Mx, G, C)                 # device-local
+            return None, C, err, g, late_buf
         on_loc, delays_loc = qinfo[1], qinfo[2]
-        covered_x = jnp.take(qinfo[4] > 0, region_ids)   # my pod's on-time
+        covered_x = jnp.take(qinfo[4] > 0, region_ids_loc)  # my pod's
         fresh = jnp.where(on_loc[:, None], G, 0.0)
         contrib = jnp.where(covered_x[None, :], fresh / denom, C / n_pop)
         g, err = _psum_payload(contrib.sum(axis=0) + late_buf[0], err)
@@ -901,13 +952,17 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
             [late_buf[1:], jnp.zeros_like(late_buf[:1])], axis=0) + adds
         dropped = delays_loc > qspec.max_delay
         C = jnp.where(Mx & ~dropped[:, None], G, C)
-        return g, C, err, Mx, late_buf
+        return None, C, err, g, late_buf
 
     def finish_step(x, g):
-        if curvature == "dense":
-            step = jax.scipy.linalg.cho_solve((cho_c, cho_lower), g)
+        if curvature == "diag":
+            step = scatter_rows(g / project_diag(hdiag, mu))
+        elif split_d:
+            step = _blocked_solve_panels(
+                chol, g, model_axis=model_axis, n_model=n_model,
+                me=me_m, row_start=row_start, dim=d)
         else:
-            step = g / project_diag(hdiag, mu)
+            step = jax.scipy.linalg.cho_solve((chol, cho_lower), g)
         return x - lr * step
 
     def round_obs(sampled):
@@ -927,11 +982,13 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
             pb = jnp.float32(0.0)
         return telem_count, round_t, pb
 
-    def diagnostics(Mx, work, count_disp):
-        if hier:  # pod-local psums aren't replicated; use the full mask
-            comm = work.sum().astype(jnp.int32)
-        else:
-            comm = jax.lax.psum(Mx.sum(), axis_name)
+    def diagnostics(sampled):
+        # uplink floats, from the replicated full-mask work (no extra
+        # psum); comm stays FULL coverage (late workers still transmit)
+        # while the coverage/τ diagnostics see the displayed (on-time,
+        # pod-resolved when hier) counts
+        work, count_disp = sampled[3], sampled[7]
+        comm = work.sum()
         cov_mean, min_count, min_cov_count = _round_diagnostics(
             count_disp > 0, count_disp, n_pop)
         return comm, cov_mean, min_count, min_cov_count
@@ -939,13 +996,13 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
     ctrl_state0 = controller.init_state(N, Q)
     telem0 = initial_telemetry(N, Q)
     late_buf0 = (() if qspec is None
-                 else jnp.zeros((qspec.max_delay, d)))
-    err0 = (() if comp is None else jnp.zeros(d))
+                 else jnp.zeros((qspec.max_delay, p)))
+    err0 = (() if comp is None else jnp.zeros(p))
     if overlap:
         def body(carry, t):
             x, C, err, late_buf, ctrl_state, telem, sampled = carry
-            g, C, err, Mx, late_buf = round_update(x, C, err, late_buf,
-                                                   sampled)  # psum issued
+            x_new, C, err, g, late_buf = round_update(
+                x, C, err, late_buf, sampled)                # psum issued
             # overlap window: fold round t's observations into the
             # telemetry, sample round t+1 (controller step + count psum),
             # and compute round t's diagnostics — none of it touches g
@@ -953,11 +1010,11 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
             telem = next_telemetry(telem, count_obs, sampled[3],
                                    sampled[4])
             nxt, ctrl_state = sample_round(t + 1, ctrl_state, telem)
-            comm, cov_mean, min_count, min_cov_count = diagnostics(
-                Mx, sampled[3], sampled[7])
-            x = finish_step(x, g)             # first consumer of the psum
-            return (x, C, err, late_buf, ctrl_state, telem, nxt), (
-                x, cov_mean, comm, min_count, min_cov_count,
+            comm, cov_mean, min_count, min_cov_count = diagnostics(sampled)
+            if x_new is None:
+                x_new = finish_step(x, g)     # first consumer of the psum
+            return (x_new, C, err, late_buf, ctrl_state, telem, nxt), (
+                x_new, cov_mean, comm, min_count, min_cov_count,
                 round_t, telem.stale_q.max(), sampled[6].sum(), pb)
 
         nxt0, ctrl_state0 = sample_round(1, ctrl_state0, telem0)
@@ -966,16 +1023,16 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
         def body(carry, t):
             x, C, err, late_buf, ctrl_state, telem = carry
             sampled, ctrl_state = sample_round(t, ctrl_state, telem)
-            g, C, err, Mx, late_buf = round_update(x, C, err, late_buf,
-                                                   sampled)
-            x = finish_step(x, g)
+            x_new, C, err, g, late_buf = round_update(
+                x, C, err, late_buf, sampled)
+            if x_new is None:
+                x_new = finish_step(x, g)
             count_obs, round_t, pb = round_obs(sampled)
             telem = next_telemetry(telem, count_obs, sampled[3],
                                    sampled[4])
-            comm, cov_mean, min_count, min_cov_count = diagnostics(
-                Mx, sampled[3], sampled[7])
-            return (x, C, err, late_buf, ctrl_state, telem), (
-                x, cov_mean, comm, min_count, min_cov_count,
+            comm, cov_mean, min_count, min_cov_count = diagnostics(sampled)
+            return (x_new, C, err, late_buf, ctrl_state, telem), (
+                x_new, cov_mean, comm, min_count, min_cov_count,
                 round_t, telem.stale_q.max(), sampled[6].sum(), pb)
 
         init_carry = (x1, C0, err0, late_buf0, ctrl_state0, telem0)
@@ -987,7 +1044,8 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
         def window(ocarry, w):
             """One exchange window: ``period`` pod-local rounds, then the
             single pod-axis collective of the loop — the anchored-delta
-            exchange (see ``_hier_scan_rounds`` for the math)."""
+            exchange on the replicated iterate (see ``_hier_scan_rounds``
+            for the math)."""
             carry, anchor, err_pod = ocarry
             ts_w = w * period + jnp.arange(1, period + 1)
             carry, outs = jax.lax.scan(body, carry, ts_w)
@@ -1021,54 +1079,89 @@ def _sharded_rounds_body(problem, k_loop, x1, C0, cho_c, hdiag, cost, *,
     return xs, cov, comm, tau, tau_cov, times, stale, cbytes, pbytes
 
 
-_SHARDED_STATIC = ("mesh", "axis_name", "num_rounds", "num_regions",
-                   "controller", "mu", "lr", "curvature", "cho_lower",
-                   "num_workers", "overlap", "qspec", "comp", "pod_axis",
-                   "hspec")
+def _sharded_out_specs(pod_axis: str, hspec: HierarchySpec | None):
+    """Outputs are replicated by construction (every x-update flows
+    through the psum); a hierarchical run stacks its per-pod iterates
+    along the pod axis."""
+    if hspec is not None:
+        return (P(None, pod_axis, None),) + (P(),) * 8
+    return (P(),) * 9
 
 
-def _sharded_engine(problem, k_loop, x1, C0, cho_c, hdiag, cost, *, mesh,
-                    axis_name, num_rounds, num_regions, controller, mu, lr,
-                    curvature, cho_lower, num_workers, overlap, qspec=None,
-                    comp=None, pod_axis="pod", hspec=None):
+_SHARDED_STATIC = ("mesh", "data_axis", "model_axis", "pod_axis",
+                   "num_rounds", "num_regions", "controller", "mu", "lr",
+                   "curvature", "use_kernel", "interpret", "cho_lower",
+                   "overlap", "qspec", "comp", "hspec")
+
+
+def _sharded_engine(problem, k_loop, x1, C0, chol, hdiag, cost, *, mesh,
+                    data_axis, model_axis, pod_axis, num_rounds,
+                    num_regions, controller, mu, lr, curvature, use_kernel,
+                    interpret, cho_lower, overlap, qspec=None, comp=None,
+                    hspec=None):
+    """The round loop shard-mapped over the placement's mesh, from an init
+    state computed outside it (replicated, or the O(d) diag init)."""
     body = functools.partial(
-        _sharded_rounds_body, axis_name=axis_name, num_rounds=num_rounds,
-        num_regions=num_regions, controller=controller, mu=mu, lr=lr,
-        curvature=curvature, cho_lower=cho_lower, num_workers=num_workers,
+        _sharded_rounds_body, data_axis=data_axis, model_axis=model_axis,
+        num_rounds=num_rounds, num_regions=num_regions,
+        controller=controller, mu=mu, lr=lr, curvature=curvature,
+        use_kernel=use_kernel, interpret=interpret, cho_lower=cho_lower,
+        num_workers=problem.num_workers, n_data=mesh.shape[data_axis],
+        n_model=1 if model_axis is None else mesh.shape[model_axis],
         overlap=overlap, qspec=qspec, comp=comp, pod_axis=pod_axis,
         hspec=hspec)
     # hier: workers shard JOINTLY over (pod, data) — pod-major layout,
-    # matching the body's (me_pod * n_data + shard) slice arithmetic
-    waxis = (pod_axis, axis_name) if hspec is not None else axis_name
-    in_specs = (_worker_sharded_specs(problem, waxis),
-                _replicated_specs(k_loop), _replicated_specs(x1),
-                P(waxis, None), _replicated_specs(cho_c),
-                _replicated_specs(hdiag), _replicated_specs(cost))
-    # outputs are replicated by construction (every x-update flows through
-    # the psum); check_vma=False because the replication checker cannot
-    # track the axis_index-based worker slicing.  Hier: the per-pod
-    # iterates stack along the pod axis; everything else stays replicated.
-    out_specs = ((P(None, pod_axis, None),) + (P(),) * 8
-                 if hspec is not None else (P(),) * 9)
+    # matching the body's (me_pod * n_data + me_d) slice arithmetic
+    waxis = (pod_axis, data_axis) if hspec is not None else data_axis
+    if model_axis is None:
+        problem_spec, memory_spec = (_worker_sharded_specs(problem, waxis),
+                                     P(waxis, None))
+        hdiag_spec = _replicated_specs(hdiag)
+    else:
+        from ..launch.shard import ranl2d_pspecs
+        specs = ranl2d_pspecs(problem, worker_axis=waxis,
+                              dim_axis=model_axis)
+        problem_spec, memory_spec = specs["problem"], specs["memory"]
+        hdiag_spec = specs["hdiag"]
+    in_specs = (problem_spec, _replicated_specs(k_loop),
+                _replicated_specs(x1), memory_spec, _replicated_specs(chol),
+                hdiag_spec, _replicated_specs(cost))
+    # check_vma=False: the replication checker cannot track the
+    # axis_index-based worker slicing
     fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
-    return fn(problem, k_loop, x1, C0, cho_c, hdiag, cost)
+                   out_specs=_sharded_out_specs(pod_axis, hspec),
+                   check_vma=False)
+    return fn(problem, k_loop, x1, C0, chol, hdiag, cost)
 
 
 _sharded_jit = functools.partial(
     jax.jit, static_argnames=_SHARDED_STATIC)(_sharded_engine)
 
 
-def _check_mesh(problem, mesh, axis_name: str):
-    if axis_name not in mesh.axis_names:
-        raise ValueError(f"mesh {mesh.axis_names} has no "
-                         f"{axis_name!r} axis to shard workers over")
-    n_dev = mesh.shape[axis_name]
-    if problem.num_workers % n_dev:
+def _check_mesh(problem, mesh, data_axis: str, model_axis: str | None = None):
+    """Validate the placement's mesh: its axes, and that the workers
+    (and, with ``model_axis``, the dimension) divide across them."""
+    if model_axis is None:
+        if data_axis not in mesh.axis_names:
+            raise ValueError(f"mesh {mesh.axis_names} has no "
+                             f"{data_axis!r} axis to shard workers over")
+    else:
+        for ax in (data_axis, model_axis):
+            if ax not in mesh.axis_names:
+                raise ValueError(
+                    f"mesh {mesh.axis_names} has no {ax!r} axis — the "
+                    f"sharded2d engine needs a ({data_axis!r}, "
+                    f"{model_axis!r}) mesh")
+    n_data = mesh.shape[data_axis]
+    if problem.num_workers % n_data:
         raise ValueError(
             f"num_workers={problem.num_workers} must divide evenly across "
-            f"the {n_dev} devices of the {axis_name!r} mesh axis")
-    return n_dev
+            f"the {n_data} devices of the {data_axis!r} mesh axis")
+    n_model = 1 if model_axis is None else mesh.shape[model_axis]
+    if problem.dim % n_model:
+        raise ValueError(
+            f"dim={problem.dim} must divide evenly across the {n_model} "
+            f"devices of the {model_axis!r} mesh axis")
 
 
 def _check_pod_mesh(problem, mesh, axis_name: str, pod_axis: str,
@@ -1094,71 +1187,137 @@ def _check_pod_mesh(problem, mesh, axis_name: str, pod_axis: str,
             f"{n_data} devices of the {axis_name!r} mesh axis")
 
 
-def _sharded_args(problem, key, opts: RanlOptions, *, mesh, axis_name,
-                  controller, cost, pod_axis: str = "pod"):
-    _check_mesh(problem, mesh, axis_name)
+def _sharded_projection(opts: RanlOptions, model_axis: str | None) -> str:
+    """The engine default for ``projection=None``: Newton–Schulz on the
+    2-D dense path (no device may hold the d×d buffer eigh needs),
+    ``eigh`` everywhere else."""
+    if opts.projection is not None:
+        return opts.projection
+    dense2d = model_axis is not None and opts.curvature == "dense"
+    return "ns" if dense2d else "eigh"
+
+
+def _sharded_args(problem, key, opts: RanlOptions, *, mesh, data_axis,
+                  model_axis, controller, cost, pod_axis: str = "pod",
+                  abstract: bool = False):
+    """-> (jitted_engine, args, static) for the placement and curvature.
+
+    2-D dense: the ENTIRE program — init included — is one shard_map'd
+    computation over (problem, key, cost), so lowering it exposes every
+    phase to the HLO memory/communication assertions and nothing
+    replicated ever materializes host-side.  Otherwise the init runs
+    host-side exactly as in the scan engine (replicated on the
+    ``("data",)`` placement, including its ``projection`` and
+    ``hessian_rank`` knobs; the O(d) Hutchinson init on the 2-D one) and
+    only the round loop is shard_map'd; with ``abstract=True`` the init
+    is traced to avals via ``jax.eval_shape`` so lowering pays no
+    compute.
+    """
+    _check_mesh(problem, mesh, data_axis, model_axis)
     hspec = opts.hierarchy_spec()
     if hspec is not None:
-        _check_pod_mesh(problem, mesh, axis_name, pod_axis, hspec,
+        _check_pod_mesh(problem, mesh, data_axis, pod_axis, hspec,
                         int(opts.num_rounds))
     controller, cost = _hetero_defaults(problem, opts.policy, controller,
                                         cost)
-    projection = opts.projection or "eigh"
+    if (model_axis is not None and opts.curvature == "dense"
+            and opts.projection == "eigh"):
+        raise ValueError(
+            "projection='eigh' is not implementable on the 2-D dense path "
+            "(no device may hold a d×d buffer) — use projection='ns' or "
+            "leave projection=None for the engine default")
+    projection = _sharded_projection(opts, model_axis)
     cfg = _config(problem, mu=opts.mu, lr=opts.lr,
                   curvature=opts.curvature,
                   hutchinson_samples=opts.hutchinson_samples,
                   projection=projection)
     hutch = cfg.pop("hutch_samples")
-    k_init, k_loop = jax.random.split(key)
-    x1, C0, cho_c, cho_lower, hdiag = _init_phase(
-        problem, k_init, mu=cfg["mu"], lr=cfg["lr"],
-        curvature=cfg["curvature"], hutch_samples=hutch,
-        projection=projection, ns_iters=opts.ns_iters,
-        hessian_rank=opts.hessian_rank)
-    args = (problem, k_loop, x1, C0, cho_c, hdiag, cost)
-    static = dict(mesh=mesh, axis_name=axis_name,
-                  num_rounds=int(opts.num_rounds),
+    static = dict(mesh=mesh, data_axis=data_axis, model_axis=model_axis,
+                  pod_axis=pod_axis, num_rounds=int(opts.num_rounds),
                   num_regions=int(opts.num_regions),
-                  controller=controller, cho_lower=cho_lower,
-                  num_workers=problem.num_workers,
+                  controller=controller, mu=cfg["mu"], lr=cfg["lr"],
                   overlap=bool(opts.overlap), qspec=opts.quorum_spec(),
-                  comp=opts.compression_spec(), pod_axis=pod_axis,
-                  hspec=hspec, **cfg)
-    return args, static
+                  comp=opts.compression_spec(), hspec=hspec)
+    if model_axis is not None and cfg["curvature"] == "dense":
+        static["ns_iters"] = (opts.ns_iters if opts.ns_iters == "auto"
+                              else int(opts.ns_iters))
+        return _sharded2d_dense_jit, (problem, key, cost), static
+
+    init_static = {}
+
+    def make_args(problem, key):
+        k_init, k_loop = jax.random.split(key)
+        x1, C0, chol, init_static["cho_lower"], hdiag = _init_phase(
+            problem, k_init, mu=cfg["mu"], lr=cfg["lr"],
+            curvature=cfg["curvature"], hutch_samples=hutch,
+            projection=projection, ns_iters=opts.ns_iters,
+            hessian_rank=opts.hessian_rank)
+        return problem, k_loop, x1, C0, chol, hdiag
+
+    if abstract:
+        args = jax.eval_shape(make_args, problem, key)
+    else:
+        args = make_args(problem, key)
+    static.update(curvature=cfg["curvature"],
+                  use_kernel=bool(opts.use_kernel), interpret=None,
+                  **init_static)
+    return _sharded_jit, (*args, cost), static
 
 
 def _run_sharded(problem, key, opts: RanlOptions, *, mesh,
-                 axis_name: str = "data", pod_axis: str = "pod",
-                 controller=None, cost=None):
-    """Algorithm 1 with the worker axis sharded across ``mesh`` devices
-    (engine ``"sharded"`` of ``repro.run``).
+                 data_axis: str = "data", model_axis: str | None = None,
+                 pod_axis: str = "pod", controller=None, cost=None):
+    """Algorithm 1 with the worker axis sharded across the ``data_axis``
+    devices of ``mesh`` — and, with ``model_axis``, the parameter
+    dimension across that axis (engines ``"sharded"`` and ``"sharded2d"``
+    of ``repro.run``).
 
-    The init phase runs replicated (identical to the scan engine,
-    including its ``projection`` knob); the round loop runs under
-    ``shard_map`` with ``problem``'s worker-indexed leaves and the
-    gradient memory C partitioned over ``axis_name`` and server
-    aggregation expressed as ``psum`` collectives.  ``opts.overlap``
-    selects the double-buffered round loop (next round's mask sampling
-    and coverage-count psum pipelined into the param-psum window —
-    identical math, see ``_sharded_rounds_body``).  Trajectories match
-    the scan engine to reduction-reorder tolerance (parity-pinned at
-    1e-6 in tests/test_multidevice.py).  The aggregation is always the
-    pure-jnp collective form — ``use_kernel`` has no sharded
-    counterpart.  Quorum mode folds the device-local late buffer into
-    the round's one param-sized psum (no new collective — see the body).
+    The round loop runs under ``shard_map`` with ``problem``'s
+    worker-indexed leaves and the gradient memory C partitioned, and
+    server aggregation expressed as ``psum`` collectives (see
+    ``_sharded_rounds_body``).  On the ``("data",)`` placement the init
+    phase runs replicated (identical to the scan engine, including its
+    ``projection`` knob) and the aggregation is always the pure-jnp
+    collective form; trajectories match the scan engine to
+    reduction-reorder tolerance (parity-pinned at 1e-6 in
+    tests/test_multidevice.py).
 
-    Requires ``num_workers`` divisible by the ``axis_name`` mesh extent.
+    With ``model_axis`` each device holds d/n_model-row slices of C, the
+    pruned gradients, ``hdiag`` and the region masks, and the per-round
+    param all-reduce shrinks to a psum of d/n_model floats over ONLY the
+    data axis.  ``curvature="dense"`` runs the WHOLE dense path sharded,
+    init included: the mean Hessian accumulates as model-axis row panels
+    (``worker_hessian_rows``), the Definition-4 projection is the
+    matmul-only Newton–Schulz iteration over those panels (``ns_iters``
+    controls its step count — see ``hessian.project_psd_ns``), and the
+    blocked right-looking factorization + blocked triangular solves
+    replace the replicated Cholesky.  No device materializes a d×d
+    buffer at ANY phase, proven on compiled HLO.  The single-device
+    oracle of this path is the scan engine with ``projection="ns"``.
+    ``curvature="diag"`` keeps the O(d)-state Hutchinson init; its
+    estimate and the fused Pallas ``ranl_update`` kernel run on local
+    d-slices unchanged (the kernel engages on pure model-parallel
+    meshes, where every worker is device-local).  Parity with the
+    matching single-device oracle is pinned at 1e-5 on 1x1, 2x2 and 1x4
+    emulated meshes.
+
+    ``opts.overlap`` selects the double-buffered round loop (next round's
+    mask sampling and coverage-count psum pipelined into the param-psum
+    window — identical math).  Requires ``num_workers`` divisible by the
+    data axis extent and ``dim`` divisible by the model axis extent.
     """
     if opts.num_rounds <= 0:  # no rounds -> no communication to shard
-        _check_mesh(problem, mesh, axis_name)   # still validate the mesh
-        return _run_scan(problem, key, opts, controller=controller,
+        _check_mesh(problem, mesh, data_axis, model_axis)
+        fallback = opts.merged(projection=_sharded_projection(opts,
+                                                              model_axis))
+        return _run_scan(problem, key, fallback, controller=controller,
                          cost=cost)
-    args, static = _sharded_args(problem, key, opts, mesh=mesh,
-                                 axis_name=axis_name,
-                                 controller=controller, cost=cost,
-                                 pod_axis=pod_axis)
+    engine, args, static = _sharded_args(
+        problem, key, opts, mesh=mesh, data_axis=data_axis,
+        model_axis=model_axis, controller=controller, cost=cost,
+        pod_axis=pod_axis)
     with span("ranl.rounds"):
-        out = _sharded_jit(*args, **static)
+        out = engine(*args, **static)
     return _sharded_result(problem, opts, static["hspec"], out)
 
 
@@ -1182,34 +1341,34 @@ def _sharded_result(problem, opts: RanlOptions, hspec, out):
 
 
 def _lower_sharded(problem, key, opts: RanlOptions, *, mesh,
-                   axis_name: str = "data", pod_axis: str = "pod",
-                   controller=None, cost=None):
-    """Lower (without running) the sharded round loop.
+                   data_axis: str = "data", model_axis: str | None = None,
+                   pod_axis: str = "pod", controller=None, cost=None):
+    """Lower (without running) a sharded engine's program.
 
-    Returns the ``jax.stages.Lowered`` for the same computation the
-    ``"sharded"`` engine executes; ``.compile().as_text()`` is the
-    partitioned HLO that ``launch.hlo_analysis`` can inventory — the
-    one-param-sized-all-reduce-per-round invariant is asserted on it
-    (``overlap=True`` included: pipelining moves collectives across
-    iteration boundaries but never adds one; controller-driven and
-    quorum runs included: the controller steps replicated and the late
-    fold rides the existing psum, so neither adds a collective).
+    Returns the ``jax.stages.Lowered`` for the same computation
+    ``_run_sharded`` executes; the init runs as avals, so nothing
+    executes and configs far beyond this host's memory can be inspected.
+    ``.compile().as_text()`` is the partitioned HLO that
+    ``launch.hlo_analysis`` inventories: exactly one param-sized
+    all-reduce over the data axis per round (``overlap=True`` included:
+    pipelining moves collectives across iteration boundaries but never
+    adds one; controller-driven and quorum runs included: the controller
+    steps replicated and the late fold rides the existing psum), and,
+    for the 2-D dense program, whose HLO carries every phase, no
+    per-device buffer above ~d²/n_model bytes anywhere, init included.
     """
-    args, static = _sharded_args(problem, key, opts, mesh=mesh,
-                                 axis_name=axis_name,
-                                 controller=controller, cost=cost,
-                                 pod_axis=pod_axis)
-    return _sharded_jit.lower(*args, **static)
+    engine, args, static = _sharded_args(
+        problem, key, opts, mesh=mesh, data_axis=data_axis,
+        model_axis=model_axis, controller=controller, cost=cost,
+        pod_axis=pod_axis, abstract=True)
+    return engine.lower(*args, **static)
 
 
 # --------------------------------------------------------------------------
-# dimension-sharded engine: ("data", "model") mesh — the worker axis is
-# partitioned over "data" exactly as in run_ranl_sharded, and the parameter
-# dimension d is partitioned over "model": each device holds d/n_model-row
-# slices of the gradient memory C, the pruned gradients G, hdiag, the
-# region coordinate masks, and — for curvature="dense" — a (d/n_model, d)
-# row panel of the Cholesky factor of [H]_μ, so no device ever holds a
-# d×d curvature buffer.
+# the 2-D dense program: on a ("data", "model") mesh with
+# curvature="dense" each device holds a (d/n_model, d) row panel of the
+# Cholesky factor of [H]_μ, built in-program, so no device ever holds a
+# d×d curvature buffer
 # --------------------------------------------------------------------------
 
 def _factor_sharded2d_body(h_panel, *, model_axis: str, n_model: int):
@@ -1284,370 +1443,6 @@ def _blocked_solve_panels(l_panel, g_local, *, model_axis: str,
     return s
 
 
-def _sharded2d_rounds_body(problem, k_loop, x1, C0, chol, hdiag, cost, *,
-                           data_axis: str, model_axis: str, num_rounds: int,
-                           num_regions: int, controller, mu: float,
-                           lr: float, curvature: str, use_kernel: bool,
-                           interpret: bool | None, num_workers: int,
-                           n_data: int, n_model: int, overlap: bool,
-                           qspec: QuorumSpec | None = None,
-                           comp: CompressionSpec | None = None,
-                           pod_axis: str = "pod",
-                           hspec: HierarchySpec | None = None):
-    """Per-device round loop on the 2-D mesh (runs under ``shard_map`` for
-    the diag path, called inline by ``_sharded2d_dense_body`` for dense).
-
-    ``problem``/``C0`` arrive worker-sharded over ``data_axis`` and (for
-    O(d²) problem state and C) dimension-sharded over ``model_axis``;
-    ``x1`` is replicated (the gradient oracles need the full iterate);
-    ``chol``/``hdiag`` are row-sharded over ``model_axis``.  Each round
-    issues one region-sized psum (coverage counts) and exactly ONE
-    param-SHARD-sized psum over the DATA axis (the single-reduction
-    aggregate of d/n_model floats); the dense solve adds model-axis-only
-    block broadcasts.  C never leaves the device that owns its
-    (worker, dimension) tile.
-
-    ``overlap=True`` software-pipelines the loop exactly like the 1-D
-    engine: round t+1's mask/key sampling and coverage-count psum run in
-    the window between issuing round t's param-shard psum and consuming
-    it in the solve — identical values, identical reductions.  The
-    controller steps replicated on the full telemetry (see the 1-D body)
-    and adds no collective.
-
-    Quorum mode mirrors the 1-D body on the local column slice: the
-    split is computed replicated in ``sample_round``, the device-local
-    ``(max_delay, p)`` late-buffer tile folds into the round's one
-    data-axis param-shard psum, and the fused kernel path is bypassed
-    (it has no late-fold form).
-
-    With ``comp`` that one data-axis psum carries a compressed payload
-    (``psum_compressed`` on the local d/n_model-column slice, per-device
-    error-feedback residual (p,) in the carry); top-k region selection is
-    per-model-shard (each shard keeps the locally heaviest regions — the
-    residual absorbs the difference).  The fused kernel path is bypassed
-    (``comp`` changes the wire format of the psum the kernel fuses away).
-    ``comp=None`` compiles the uncompressed loop unchanged.
-
-    With ``hspec`` the worker axis shards jointly over ``(pod_axis,
-    data_axis)`` and the loop nests into exchange windows exactly as in
-    the 1-D body: every in-round collective reduces over ``data_axis``
-    (pod-local) or ``model_axis`` (pod-internal assembly) only, and the
-    window-tail anchored-delta exchange — the loop's ONLY ``pod_axis``
-    collective, one d-sized psum issued by every model shard on its
-    replicated iterate — carries multiplier E = rounds/period in HLO.
-    """
-    from ..hetero.cost import pod_exchange_time, quorum_split, worker_times
-    from ..hetero.controller import initial_telemetry, next_telemetry
-    from ..kernels.region_aggregate import local_region_ids
-    N, Q = num_workers, num_regions
-    d = x1.shape[0]
-    p = d // n_model
-    n_local = problem.num_workers         # workers held by this shard
-    me_d = jax.lax.axis_index(data_axis)
-    me_m = jax.lax.axis_index(model_axis)
-    hier = hspec is not None
-    pods = hspec.pods if hier else 1
-    n_pop = N // pods                     # workers per pod (= N when flat)
-    me_pod = jax.lax.axis_index(pod_axis) if hier else 0
-    wstart = (me_pod * n_data + me_d) * n_local if hier else me_d * n_local
-    row_start = me_m * p
-    region_ids = contiguous_regions(d, Q)
-    region_ids_loc = local_region_ids(d, Q, row_start, p)
-    sizes_q = region_sizes(region_ids, Q)           # (Q,) static
-    local_ids = jnp.arange(n_local)
-    grad_rows = jax.vmap(
-        lambda i, xp, k: problem.worker_grad_rows(i, xp, k, row_start, p))
-    hcomp = parse_compression(hspec.compression) if hier else None
-    pod_wire = _pod_wire_bytes(comp, d)   # flat-on-topology charge
-    hier_wire = _pod_wire_bytes(hcomp, d)
-    # the fused Pallas kernel aggregates over the workers it can see, so it
-    # is exact only when this device sees ALL workers (pure model-parallel
-    # meshes); otherwise the collective jnp form is used.  It has no
-    # late-fold form, so quorum and hierarchical runs always take the jnp
-    # path.
-    kernel_ok = (use_kernel and curvature == "diag" and n_data == 1
-                 and qspec is None and comp is None and not hier)
-
-    def sample_round(t, ctrl_state, telem):
-        """Everything x-independent about round t: step the controller on
-        the FULL (N, Q) telemetry on every device (tiny, keeps the PRNG
-        stream bit-identical to the single-device engine), slice out this
-        shard's workers, reduce the coverage counts (Q ints), price the
-        round under the cost model, and (quorum mode) split it at the
-        quorum deadline."""
-        kt = jax.random.fold_in(k_loop, t)
-        M_full, ctrl_state = _controller_mask(controller, cost, ctrl_state,
-                                              telem, kt, t, N, Q)
-        gk_full = jax.random.split(jax.random.fold_in(kt, 7), N)
-        M = jax.lax.dynamic_slice_in_dim(M_full, wstart, n_local)
-        gk = jax.lax.dynamic_slice_in_dim(gk_full, wstart, n_local)
-        # pod-local counts under hier: same collective, per-pod values
-        count_q = jax.lax.psum(M.sum(axis=0), data_axis)
-        work = (M_full * sizes_q[None, :]).sum(axis=1)
-        ubytes = uplink_bytes(comp, M_full, sizes_q)
-        times = worker_times(cost, work, t, ubytes, overlap=overlap)
-        if qspec is None:
-            qinfo = ()
-            count_disp = (M_full.reshape(pods, n_pop, Q).sum(axis=1)
-                          if hier else count_q)
-        elif hier:
-            split = functools.partial(
-                quorum_split, quorum=qspec.quorum,
-                quorum_tau=qspec.quorum_tau, max_delay=qspec.max_delay)
-            deadline_p, on_p, delays_p = jax.vmap(split)(
-                times.reshape(pods, n_pop), M_full.reshape(pods, n_pop, Q))
-            count_disp = (M_full.reshape(pods, n_pop, Q)
-                          & on_p[:, :, None]).sum(axis=1)        # (P, Q)
-            count_on_loc = jax.lax.dynamic_slice_in_dim(
-                count_disp, me_pod, 1)[0]                        # my pod's
-            qinfo = (count_disp,
-                     jax.lax.dynamic_slice_in_dim(on_p.reshape(N),
-                                                  wstart, n_local),
-                     jax.lax.dynamic_slice_in_dim(delays_p.reshape(N),
-                                                  wstart, n_local),
-                     deadline_p.max(), count_on_loc)
-        else:
-            deadline, on_time, delays = quorum_split(
-                times, M_full, quorum=qspec.quorum,
-                quorum_tau=qspec.quorum_tau, max_delay=qspec.max_delay)
-            count_on = (M_full & on_time[:, None]).sum(axis=0)
-            count_disp = count_on
-            qinfo = (count_on,
-                     jax.lax.dynamic_slice_in_dim(on_time, wstart,
-                                                  n_local),
-                     jax.lax.dynamic_slice_in_dim(delays, wstart,
-                                                  n_local),
-                     deadline, count_on)
-        return (M, gk, count_q, work, times, qinfo, ubytes,
-                count_disp), ctrl_state
-
-    def scatter_rows(vec_loc):
-        """Assemble a replicated (d,) vector from local rows — one
-        model-axis psum of d floats."""
-        return jax.lax.psum(
-            jax.lax.dynamic_update_slice(jnp.zeros(d, vec_loc.dtype),
-                                         vec_loc, (row_start,)), model_axis)
-
-    def _psum_payload(y_loc, err):
-        """The round's ONE data-axis param-shard all-reduce — compressed
-        on the local column slice when ``comp`` is set."""
-        if comp is None:
-            return jax.lax.psum(y_loc, data_axis), err
-        return psum_compressed(comp, y_loc, err, axis_name=data_axis,
-                               n_agg=n_data, region_ids=region_ids_loc,
-                               num_regions=Q)
-
-    def round_update(x, C, err, late_buf, sampled):
-        """The x-dependent half, up to issuing the round's main
-        collective.  Returns (x_new, C, err, g_loc, late_buf): for the
-        kernel path the new iterate directly (its model-axis assembly
-        psum issued), otherwise ``g_loc`` — the result of the round's ONE
-        data-axis param-shard all-reduce — for ``finish_step`` to
-        consume.  Quorum mode folds the local late-buffer tile into that
-        same psum and enqueues this round's late work (see the 1-D
-        body)."""
-        M, gk, count_q, qinfo = sampled[0], sampled[1], sampled[2], sampled[5]
-        Mx_full = expand_mask(M, region_ids)        # (n_local, d)
-        Mx = expand_mask(M, region_ids_loc)         # (n_local, p) local cols
-        x_pruned = jnp.where(Mx_full, x[None, :], 0.0)
-        G = grad_rows(local_ids, x_pruned, gk) * Mx  # local gradient rows
-        if kernel_ok:
-            from ..kernels.region_aggregate import ranl_update
-            # all workers are local: the fused aggregate + projected-Newton
-            # kernel runs on this device's d-slice unchanged
-            x_loc = jax.lax.dynamic_slice(x, (row_start,), (p,))
-            x_loc, C = ranl_update(x_loc, hdiag, G, Mx, C, mu=mu, lr=lr,
-                                   interpret=interpret)
-            return scatter_rows(x_loc), C, err, None, late_buf
-        # single-reduction aggregation on the local d-slice: the
-        # worker-axis sum below is the round's ONE data-axis param-shard
-        # all-reduce (d/n_model floats)
-        count_x = jnp.take(count_q, region_ids_loc)
-        denom = jnp.maximum(count_x, 1).astype(G.dtype)
-        if qspec is None:
-            covered_x = jnp.take(count_q > 0, region_ids_loc)
-            contrib = jnp.where(covered_x[None, :], G / denom, C / n_pop)
-            g_loc, err = _psum_payload(contrib.sum(axis=0), err)
-            C = jnp.where(Mx, G, C)                 # device-local tile
-            return None, C, err, g_loc, late_buf
-        on_loc, delays_loc = qinfo[1], qinfo[2]
-        covered_x = jnp.take(qinfo[4] > 0, region_ids_loc)  # my pod's
-        fresh = jnp.where(on_loc[:, None], G, 0.0)
-        contrib = jnp.where(covered_x[None, :], fresh / denom, C / n_pop)
-        g_loc, err = _psum_payload(contrib.sum(axis=0) + late_buf[0], err)
-        adds = late_fold_updates(G, Mx, count_x.astype(G.dtype),
-                                 delays_loc, gamma=qspec.gamma,
-                                 max_delay=qspec.max_delay)
-        late_buf = jnp.concatenate(
-            [late_buf[1:], jnp.zeros_like(late_buf[:1])], axis=0) + adds
-        dropped = delays_loc > qspec.max_delay
-        C = jnp.where(Mx & ~dropped[:, None], G, C)
-        return None, C, err, g_loc, late_buf
-
-    def finish_step(x, g_loc):
-        if curvature == "dense":
-            step = _blocked_solve_panels(
-                chol, g_loc, model_axis=model_axis, n_model=n_model,
-                me=me_m, row_start=row_start, dim=d)
-        else:
-            step = scatter_rows(g_loc / project_diag(hdiag, mu))
-        return x - lr * step
-
-    def round_obs(sampled):
-        """(telemetry count, round-time trace, inter-pod bytes) for this
-        round — on-time counts and the quorum deadline in quorum mode.
-        Flat rounds on a pod topology charge the param aggregate's
-        inter-pod crossing here (hier rounds pay only at the exchange)."""
-        times, qinfo, count_disp = sampled[4], sampled[5], sampled[7]
-        telem_count = count_disp.sum(axis=0) if hier else count_disp
-        round_t = times.max() if qspec is None else qinfo[3]
-        if cost.pod_bw is not None and not hier:
-            round_t = round_t + pod_exchange_time(cost, pod_wire)
-            pb = jnp.float32(pod_wire)
-        else:
-            pb = jnp.float32(0.0)
-        return telem_count, round_t, pb
-
-    def diagnostics(sampled):
-        # uplink floats, from the replicated full-mask work (no extra
-        # psum); comm stays FULL coverage (late workers still transmit)
-        # while the coverage/τ diagnostics see the displayed (on-time,
-        # pod-resolved when hier) counts
-        work, count_disp = sampled[3], sampled[7]
-        comm = work.sum()
-        cov_mean, min_count, min_cov_count = _round_diagnostics(
-            count_disp > 0, count_disp, n_pop)
-        return comm, cov_mean, min_count, min_cov_count
-
-    ctrl_state0 = controller.init_state(N, Q)
-    telem0 = initial_telemetry(N, Q)
-    late_buf0 = (() if qspec is None
-                 else jnp.zeros((qspec.max_delay, p)))
-    err0 = (() if comp is None else jnp.zeros(p))
-    if overlap:
-        def body(carry, t):
-            x, C, err, late_buf, ctrl_state, telem, sampled = carry
-            x_new, C, err, g_loc, late_buf = round_update(
-                x, C, err, late_buf, sampled)
-            # overlap window: round t's telemetry fold + diagnostics and
-            # round t+1's sampling + count psum — none of it touches the
-            # in-flight psum
-            count_obs, round_t, pb = round_obs(sampled)
-            telem = next_telemetry(telem, count_obs, sampled[3],
-                                   sampled[4])
-            nxt, ctrl_state = sample_round(t + 1, ctrl_state, telem)
-            comm, cov_mean, min_count, min_cov_count = diagnostics(sampled)
-            if x_new is None:
-                x_new = finish_step(x, g_loc)     # first psum consumer
-            return (x_new, C, err, late_buf, ctrl_state, telem, nxt), (
-                x_new, cov_mean, comm, min_count, min_cov_count,
-                round_t, telem.stale_q.max(), sampled[6].sum(), pb)
-
-        nxt0, ctrl_state0 = sample_round(1, ctrl_state0, telem0)
-        init_carry = (x1, C0, err0, late_buf0, ctrl_state0, telem0, nxt0)
-    else:
-        def body(carry, t):
-            x, C, err, late_buf, ctrl_state, telem = carry
-            # x: (d,) replicated; C: (n_local, p)
-            sampled, ctrl_state = sample_round(t, ctrl_state, telem)
-            x_new, C, err, g_loc, late_buf = round_update(
-                x, C, err, late_buf, sampled)
-            if x_new is None:
-                x_new = finish_step(x, g_loc)
-            count_obs, round_t, pb = round_obs(sampled)
-            telem = next_telemetry(telem, count_obs, sampled[3],
-                                   sampled[4])
-            comm, cov_mean, min_count, min_cov_count = diagnostics(sampled)
-            return (x_new, C, err, late_buf, ctrl_state, telem), (
-                x_new, cov_mean, comm, min_count, min_cov_count,
-                round_t, telem.stale_q.max(), sampled[6].sum(), pb)
-
-        init_carry = (x1, C0, err0, late_buf0, ctrl_state0, telem0)
-
-    if not hier:
-        ts = jnp.arange(1, num_rounds + 1)
-        _, outs = jax.lax.scan(body, init_carry, ts)
-    else:
-        def window(ocarry, w):
-            """One exchange window, ending in the loop's only pod-axis
-            collective: the anchored-delta exchange on the replicated
-            iterate (see ``_hier_scan_rounds`` for the math)."""
-            carry, anchor, err_pod = ocarry
-            ts_w = w * period + jnp.arange(1, period + 1)
-            carry, outs = jax.lax.scan(body, carry, ts_w)
-            x = carry[0]
-            delta = x - anchor
-            if hcomp is None:
-                total = jax.lax.psum(delta, pod_axis)
-            else:
-                total, err_pod = psum_compressed(
-                    hcomp, delta, err_pod, axis_name=pod_axis,
-                    n_agg=pods, region_ids=region_ids, num_regions=Q)
-            xbar = anchor + total / pods
-            x = x + hspec.gamma * (xbar - x)
-            ex_t = pod_exchange_time(cost, hier_wire)
-            outs = (outs[:5] + (outs[5].at[-1].add(ex_t),) + outs[6:8]
-                    + (outs[8].at[-1].add(hier_wire),))
-            return ((x,) + carry[1:], xbar, err_pod), outs
-
-        period = hspec.period
-        err_pod0 = () if hcomp is None else jnp.zeros(d)
-        _, outs = jax.lax.scan(window, (init_carry, x1, err_pod0),
-                               jnp.arange(num_rounds // period))
-        outs = jax.tree.map(
-            lambda a: a.reshape((num_rounds,) + a.shape[2:]), outs)
-    (xs_t, cov, comm, min_counts, min_cov_counts, times,
-     stale, cbytes, pbytes) = outs
-    xs = jnp.concatenate([jnp.stack([jnp.zeros(d), x1]), xs_t], axis=0)
-    if hier:
-        xs = xs[:, None, :]   # out_spec stacks pods along this axis
-    tau, tau_cov = _tau_pair(min_counts, min_cov_counts, n_pop)
-    return xs, cov, comm, tau, tau_cov, times, stale, cbytes, pbytes
-
-
-_SHARDED2D_STATIC = ("mesh", "data_axis", "model_axis", "num_rounds",
-                     "num_regions", "controller", "mu", "lr", "curvature",
-                     "use_kernel", "interpret", "num_workers", "n_data",
-                     "n_model", "overlap", "qspec", "comp", "pod_axis",
-                     "hspec")
-
-
-def _sharded2d_engine(problem, k_loop, x1, C0, hdiag, cost, *, mesh,
-                      data_axis, model_axis, num_rounds, num_regions,
-                      controller, mu, lr, curvature, use_kernel, interpret,
-                      num_workers, n_data, n_model, overlap, qspec=None,
-                      comp=None, pod_axis="pod", hspec=None):
-    """Diag-curvature 2-D engine: host-side O(d) init, sharded rounds."""
-    from ..launch.shard import ranl2d_pspecs
-
-    def body(problem, k_loop, x1, C0, hdiag, cost):
-        return _sharded2d_rounds_body(
-            problem, k_loop, x1, C0, None, hdiag, cost,
-            data_axis=data_axis,
-            model_axis=model_axis, num_rounds=num_rounds,
-            num_regions=num_regions, controller=controller, mu=mu, lr=lr,
-            curvature=curvature, use_kernel=use_kernel, interpret=interpret,
-            num_workers=num_workers, n_data=n_data, n_model=n_model,
-            overlap=overlap, qspec=qspec, comp=comp, pod_axis=pod_axis,
-            hspec=hspec)
-
-    waxis = (pod_axis, data_axis) if hspec is not None else data_axis
-    specs = ranl2d_pspecs(problem, worker_axis=waxis,
-                          dim_axis=model_axis)
-    in_specs = (specs["problem"], _replicated_specs(k_loop),
-                _replicated_specs(x1), specs["memory"], specs["hdiag"],
-                _replicated_specs(cost))
-    out_specs = ((P(None, pod_axis, None),) + (P(),) * 8
-                 if hspec is not None else (P(),) * 9)
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
-    return fn(problem, k_loop, x1, C0, hdiag, cost)
-
-
-_sharded2d_jit = functools.partial(
-    jax.jit, static_argnames=_SHARDED2D_STATIC)(_sharded2d_engine)
-
-
 def _sharded2d_dense_body(problem, key, cost, *, data_axis, model_axis,
                           num_rounds, num_regions, controller, mu, lr,
                           ns_iters, overlap, num_workers, n_data, n_model,
@@ -1713,215 +1508,48 @@ def _sharded2d_dense_body(problem, key, cost, *, data_axis, model_axis,
                                   n_model=n_model, me=me_m,
                                   row_start=row_start, dim=d)
     x1 = x0 - lr * step0
-    return _sharded2d_rounds_body(
+    return _sharded_rounds_body(
         problem, k_loop, x1, g0, chol, None, cost, data_axis=data_axis,
         model_axis=model_axis, num_rounds=num_rounds,
         num_regions=num_regions, controller=controller, mu=mu, lr=lr,
         curvature="dense", use_kernel=False, interpret=None,
-        num_workers=N, n_data=n_data, n_model=n_model, overlap=overlap,
-        qspec=qspec, comp=comp, pod_axis=pod_axis, hspec=hspec)
+        cho_lower=True, num_workers=N, n_data=n_data, n_model=n_model,
+        overlap=overlap, qspec=qspec, comp=comp, pod_axis=pod_axis,
+        hspec=hspec)
 
 
-_SHARDED2D_DENSE_STATIC = ("mesh", "data_axis", "model_axis", "num_rounds",
-                           "num_regions", "controller", "mu", "lr",
-                           "ns_iters", "overlap", "num_workers", "n_data",
-                           "n_model", "qspec", "comp", "pod_axis", "hspec")
+_SHARDED2D_DENSE_STATIC = ("mesh", "data_axis", "model_axis", "pod_axis",
+                           "num_rounds", "num_regions", "controller", "mu",
+                           "lr", "ns_iters", "overlap", "qspec", "comp",
+                           "hspec")
 
 
 def _sharded2d_dense_engine(problem, key, cost, *, mesh, data_axis,
-                            model_axis, num_rounds, num_regions,
+                            model_axis, pod_axis, num_rounds, num_regions,
                             controller, mu, lr, ns_iters, overlap,
-                            num_workers, n_data, n_model, qspec=None,
-                            comp=None, pod_axis="pod", hspec=None):
+                            qspec=None, comp=None, hspec=None):
     from ..launch.shard import ranl2d_pspecs
     body = functools.partial(
         _sharded2d_dense_body, data_axis=data_axis, model_axis=model_axis,
         num_rounds=num_rounds, num_regions=num_regions,
         controller=controller, mu=mu, lr=lr, ns_iters=ns_iters,
-        overlap=overlap, num_workers=num_workers, n_data=n_data,
-        n_model=n_model, qspec=qspec, comp=comp, pod_axis=pod_axis,
-        hspec=hspec)
+        overlap=overlap, num_workers=problem.num_workers,
+        n_data=mesh.shape[data_axis], n_model=mesh.shape[model_axis],
+        qspec=qspec, comp=comp, pod_axis=pod_axis, hspec=hspec)
     waxis = (pod_axis, data_axis) if hspec is not None else data_axis
     specs = ranl2d_pspecs(problem, worker_axis=waxis,
                           dim_axis=model_axis)
     in_specs = (specs["problem"], _replicated_specs(key),
                 _replicated_specs(cost))
-    out_specs = ((P(None, pod_axis, None),) + (P(),) * 8
-                 if hspec is not None else (P(),) * 9)
     fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+                   out_specs=_sharded_out_specs(pod_axis, hspec),
+                   check_vma=False)
     return fn(problem, key, cost)
 
 
 _sharded2d_dense_jit = functools.partial(
     jax.jit, static_argnames=_SHARDED2D_DENSE_STATIC)(
     _sharded2d_dense_engine)
-
-
-def _check_mesh2d(problem, mesh, data_axis: str, model_axis: str):
-    for ax in (data_axis, model_axis):
-        if ax not in mesh.axis_names:
-            raise ValueError(f"mesh {mesh.axis_names} has no {ax!r} axis "
-                             f"— run_ranl_sharded2d needs a "
-                             f"({data_axis!r}, {model_axis!r}) mesh")
-    n_data = mesh.shape[data_axis]
-    n_model = mesh.shape[model_axis]
-    if problem.num_workers % n_data:
-        raise ValueError(
-            f"num_workers={problem.num_workers} must divide evenly across "
-            f"the {n_data} devices of the {data_axis!r} mesh axis")
-    if problem.dim % n_model:
-        raise ValueError(
-            f"dim={problem.dim} must divide evenly across the {n_model} "
-            f"devices of the {model_axis!r} mesh axis")
-    return n_data, n_model
-
-
-def _sharded2d_args(problem, key, opts: RanlOptions, *, mesh, data_axis,
-                    model_axis, controller, cost, abstract: bool = False,
-                    pod_axis: str = "pod"):
-    """-> (jitted_engine, args, static) for the requested curvature.
-
-    Dense: the ENTIRE program — init included — is one shard_map'd
-    computation over (problem, key, cost), so lowering it exposes every
-    phase to the HLO memory/communication assertions and nothing
-    replicated ever materializes host-side.  Diag: the O(d)-state
-    Hutchinson init runs host-side exactly as in the scan engine and only
-    the round loop is shard_map'd (with ``abstract=True`` the init is
-    traced to avals via ``jax.eval_shape`` so lowering pays no compute).
-    """
-    n_data, n_model = _check_mesh2d(problem, mesh, data_axis, model_axis)
-    hspec = opts.hierarchy_spec()
-    if hspec is not None:
-        _check_pod_mesh(problem, mesh, data_axis, pod_axis, hspec,
-                        int(opts.num_rounds))
-    controller, cost = _hetero_defaults(problem, opts.policy, controller,
-                                        cost)
-    if opts.curvature == "dense" and opts.projection == "eigh":
-        raise ValueError(
-            "projection='eigh' is not implementable on the 2-D dense path "
-            "(no device may hold a d×d buffer) — use projection='ns' or "
-            "leave projection=None for the engine default")
-    cfg = _config(problem, mu=opts.mu, lr=opts.lr,
-                  curvature=opts.curvature,
-                  hutchinson_samples=opts.hutchinson_samples,
-                  projection=opts.projection
-                  or ("ns" if opts.curvature == "dense" else "eigh"))
-    hutch = cfg.pop("hutch_samples")
-    qspec = opts.quorum_spec()
-    comp = opts.compression_spec()
-
-    if cfg["curvature"] == "dense":
-        static = dict(mesh=mesh, data_axis=data_axis, model_axis=model_axis,
-                      num_rounds=int(opts.num_rounds),
-                      num_regions=int(opts.num_regions),
-                      controller=controller,
-                      mu=cfg["mu"], lr=cfg["lr"],
-                      ns_iters=opts.ns_iters if opts.ns_iters == "auto"
-                      else int(opts.ns_iters),
-                      overlap=bool(opts.overlap),
-                      num_workers=problem.num_workers,
-                      n_data=n_data, n_model=n_model, qspec=qspec,
-                      comp=comp, pod_axis=pod_axis, hspec=hspec)
-        return _sharded2d_dense_jit, (problem, key, cost), static
-
-    def make_args(problem, key):
-        k_init, k_loop = jax.random.split(key)
-        x1, C0, _, _, hdiag = _init_phase(
-            problem, k_init, mu=cfg["mu"], lr=cfg["lr"],
-            curvature=cfg["curvature"], hutch_samples=hutch)
-        return problem, k_loop, x1, C0, hdiag
-
-    if abstract:
-        args = jax.eval_shape(make_args, problem, key)
-    else:
-        args = make_args(problem, key)
-    static = dict(mesh=mesh, data_axis=data_axis, model_axis=model_axis,
-                  num_rounds=int(opts.num_rounds),
-                  num_regions=int(opts.num_regions),
-                  controller=controller, use_kernel=bool(opts.use_kernel),
-                  interpret=None, num_workers=problem.num_workers,
-                  n_data=n_data, n_model=n_model,
-                  overlap=bool(opts.overlap), qspec=qspec, comp=comp,
-                  pod_axis=pod_axis, hspec=hspec, **cfg)
-    return _sharded2d_jit, (*args, cost), static
-
-
-def _run_sharded2d(problem, key, opts: RanlOptions, *, mesh,
-                   data_axis: str = "data", model_axis: str = "model",
-                   pod_axis: str = "pod", controller=None, cost=None):
-    """Algorithm 1 with workers AND the parameter dimension sharded
-    (engine ``"sharded2d"`` of ``repro.run``).
-
-    2-D ``(data_axis, model_axis)`` mesh: the worker axis partitions over
-    ``data_axis`` exactly as in ``run_ranl_sharded``; the parameter
-    dimension d partitions over ``model_axis`` — per-device slices of the
-    gradient memory C, the pruned gradients G, ``hdiag``, and the region
-    coordinate masks, with the per-round param all-reduce shrunk to a
-    psum of d/n_model floats over ONLY the data axis.
-
-    ``curvature="dense"`` runs the WHOLE dense path sharded, init
-    included: the mean Hessian accumulates as model-axis row panels
-    (``worker_hessian_rows``), the Definition-4 projection is the
-    matmul-only Newton–Schulz iteration over those panels (``ns_iters``
-    controls its step count — see ``hessian.project_psd_ns``), and the
-    blocked right-looking factorization + blocked triangular solves
-    replace the replicated Cholesky.  No device materializes a d×d
-    buffer at ANY phase (per-device curvature bytes = d²/n_model plus
-    one column block of slack), proven on compiled HLO.  The
-    single-device oracle of this path is ``run_ranl(projection="ns")``.
-    ``curvature="diag"`` keeps the O(d)-state Hutchinson init; its
-    estimate and fused Pallas ``ranl_update`` kernel run on local
-    d-slices unchanged (the kernel engages on pure model-parallel
-    meshes, where every worker is device-local).
-
-    ``overlap=True`` selects the double-buffered round loop: the next
-    round's mask sampling and coverage-count psum run while the current
-    round's param-shard psum is in flight — identical math, pinned
-    exactly equal in tests.
-
-    Trajectories match the matching single-device oracle to blocked-
-    solve/NS reorder tolerance (parity-pinned at 1e-5 in
-    tests/test_multidevice.py on 1x1, 2x2 and 1x4 emulated meshes).
-    Requires ``num_workers`` divisible by the data axis extent and
-    ``dim`` divisible by the model axis extent.
-    """
-    if opts.num_rounds <= 0:  # no rounds -> nothing to shard
-        _check_mesh2d(problem, mesh, data_axis, model_axis)
-        fallback = opts.merged(
-            projection=opts.projection
-            or ("ns" if opts.curvature == "dense" else "eigh"))
-        return _run_scan(problem, key, fallback, controller=controller,
-                         cost=cost)
-    engine, args, static = _sharded2d_args(
-        problem, key, opts, mesh=mesh, data_axis=data_axis,
-        model_axis=model_axis, controller=controller, cost=cost,
-        pod_axis=pod_axis)
-    with span("ranl.rounds"):
-        out = engine(*args, **static)
-    return _sharded_result(problem, opts, static["hspec"], out)
-
-
-def _lower_sharded2d(problem, key, opts: RanlOptions, *, mesh,
-                     data_axis: str = "data", model_axis: str = "model",
-                     pod_axis: str = "pod", controller=None, cost=None):
-    """Lower (without running) the 2-D sharded program.
-
-    Genuinely compile-time: for ``curvature="dense"`` the whole program
-    (sharded init + rounds) is lowered directly — nothing executes, so
-    configs far beyond this host's memory can be inspected — and the
-    resulting ``.compile().as_text()`` partitioned HLO carries EVERY
-    phase, which is how ``launch.hlo_analysis`` proves the end-to-end
-    memory claim: no per-device buffer above ~d²/n_model bytes anywhere,
-    init included, plus exactly one data-axis param-shard all-reduce per
-    round.  For diag the host-side init is traced to avals with
-    ``jax.eval_shape`` and the round loop is lowered as before.
-    """
-    engine, args, static = _sharded2d_args(
-        problem, key, opts, mesh=mesh, data_axis=data_axis,
-        model_axis=model_axis, controller=controller, cost=cost,
-        abstract=True, pod_axis=pod_axis)
-    return engine.lower(*args, **static)
 
 
 def _config(problem, *, mu, lr, curvature, hutchinson_samples,
@@ -2245,8 +1873,7 @@ def trace_ranl(problem, key, opts: RanlOptions = RanlOptions(), *,
     collective primitives with exact ``lax.scan`` trip counts, PRNG
     consumption, dtype promotion, host-sync hazards.  Every engine
     traces the same computation it executes — the prep helpers
-    (``_scan_args`` / ``_sharded_args`` / ``_sharded2d_args`` /
-    ``_reference_program``) are shared with the run paths, only wrapped
+    (``_scan_args`` / ``_sharded_args`` / ``_reference_program``) are shared with the run paths, only wrapped
     in ``jax.make_jaxpr`` here instead of being executed.  For
     ``engine="batch"``, ``key`` is the stacked ``(B,)`` key array the
     batch engine takes.
@@ -2281,191 +1908,19 @@ def trace_ranl(problem, key, opts: RanlOptions = RanlOptions(), *,
         def program(problem, key, cost):
             return _reference_program(problem, key, cost, opts=opts,
                                       controller=ctrl)
-    elif engine == "sharded":
+    elif engine in ("sharded", "sharded2d"):
         if mesh is None:
-            raise ValueError("engine='sharded' needs a mesh to trace")
+            raise ValueError(f"engine={engine!r} needs a mesh to trace")
+        placement = (dict(data_axis=axis_name, model_axis=None)
+                     if engine == "sharded"
+                     else dict(data_axis=data_axis, model_axis=model_axis))
 
         def program(problem, key, cost):
-            args, static = _sharded_args(problem, key, opts, mesh=mesh,
-                                         axis_name=axis_name,
-                                         controller=ctrl, cost=cost,
-                                         pod_axis=pod_axis)
-            return _sharded_engine(*args, **static)
-    elif engine == "sharded2d":
-        if mesh is None:
-            raise ValueError("engine='sharded2d' needs a mesh to trace")
-
-        def program(problem, key, cost):
-            eng, args, static = _sharded2d_args(
-                problem, key, opts, mesh=mesh, data_axis=data_axis,
-                model_axis=model_axis, controller=ctrl, cost=cost,
-                pod_axis=pod_axis)
+            eng, args, static = _sharded_args(
+                problem, key, opts, mesh=mesh, controller=ctrl, cost=cost,
+                pod_axis=pod_axis, **placement)
             return eng(*args, **static)
     else:
         raise ValueError(f"unknown engine {engine!r}")
 
     return jax.make_jaxpr(program)(problem, key, cost)
-
-
-# --------------------------------------------------------------------------
-# deprecated entrypoints — thin bit-exact shims over repro.run / repro.lower
-# --------------------------------------------------------------------------
-
-def _deprecated(old: str, engine: str):
-    warnings.warn(
-        f"{old} is deprecated — use repro.run(problem, key, "
-        f"engine={engine!r}, options=RanlOptions(...)) (repro.lower for "
-        f"the lowering entrypoints); the quorum/record_every knobs only "
-        f"exist there", EngineDeprecationWarning, stacklevel=3)
-
-
-def run_ranl(problem, key, *, num_rounds: int = 30, num_regions: int = 8,
-             policy: PolicyConfig = PolicyConfig(), mu: float | None = None,
-             record_every: int = 1, curvature: str = "dense",
-             lr: float = 1.0, use_kernel: bool = True,
-             hutchinson_samples: int = 8, projection: str = "eigh",
-             ns_iters: int | str = 60, controller=None, cost=None):
-    """Deprecated: use ``repro.run(problem, key, engine="scan", ...)``."""
-    _deprecated("run_ranl", "scan")
-    from ..api import run
-    return run(problem, key, engine="scan",
-               options=RanlOptions(
-                   num_rounds=num_rounds, num_regions=num_regions,
-                   policy=policy, mu=mu, record_every=record_every,
-                   curvature=curvature, lr=lr, use_kernel=use_kernel,
-                   hutchinson_samples=hutchinson_samples,
-                   projection=projection, ns_iters=ns_iters),
-               controller=controller, cost=cost)
-
-
-def run_ranl_batch(problem, keys, *, num_rounds: int = 30,
-                   num_regions: int = 8,
-                   policy: PolicyConfig = PolicyConfig(),
-                   mu: float | None = None, curvature: str = "dense",
-                   lr: float = 1.0, use_kernel: bool = True,
-                   hutchinson_samples: int = 8, mesh=None,
-                   axis_name: str = "data", projection: str = "eigh",
-                   ns_iters: int | str = 60, controller=None, cost=None):
-    """Deprecated: use ``repro.run(problem, keys, engine="batch", ...)``."""
-    _deprecated("run_ranl_batch", "batch")
-    from ..api import run
-    return run(problem, keys, engine="batch",
-               options=RanlOptions(
-                   num_rounds=num_rounds, num_regions=num_regions,
-                   policy=policy, mu=mu, curvature=curvature, lr=lr,
-                   use_kernel=use_kernel,
-                   hutchinson_samples=hutchinson_samples,
-                   projection=projection, ns_iters=ns_iters),
-               mesh=mesh, axis_name=axis_name,
-               controller=controller, cost=cost)
-
-
-def run_ranl_sharded(problem, key, *, mesh, num_rounds: int = 30,
-                     num_regions: int = 8,
-                     policy: PolicyConfig = PolicyConfig(),
-                     mu: float | None = None, curvature: str = "dense",
-                     lr: float = 1.0, hutchinson_samples: int = 8,
-                     axis_name: str = "data", projection: str = "eigh",
-                     ns_iters: int | str = 60, overlap: bool = False,
-                     controller=None, cost=None):
-    """Deprecated: use ``repro.run(problem, key, engine="sharded", ...)``."""
-    _deprecated("run_ranl_sharded", "sharded")
-    from ..api import run
-    return run(problem, key, engine="sharded",
-               options=RanlOptions(
-                   num_rounds=num_rounds, num_regions=num_regions,
-                   policy=policy, mu=mu, curvature=curvature, lr=lr,
-                   hutchinson_samples=hutchinson_samples,
-                   projection=projection, ns_iters=ns_iters,
-                   overlap=overlap),
-               mesh=mesh, axis_name=axis_name,
-               controller=controller, cost=cost)
-
-
-def lower_ranl_sharded(problem, key, *, mesh, num_rounds: int = 30,
-                       num_regions: int = 8,
-                       policy: PolicyConfig = PolicyConfig(),
-                       mu: float | None = None, curvature: str = "dense",
-                       lr: float = 1.0, hutchinson_samples: int = 8,
-                       axis_name: str = "data", projection: str = "eigh",
-                       ns_iters: int | str = 60, overlap: bool = False,
-                       controller=None, cost=None):
-    """Deprecated: use ``repro.lower(problem, key, engine="sharded", ...)``.
-    """
-    _deprecated("lower_ranl_sharded", "sharded")
-    from ..api import lower
-    return lower(problem, key, engine="sharded",
-                 options=RanlOptions(
-                     num_rounds=num_rounds, num_regions=num_regions,
-                     policy=policy, mu=mu, curvature=curvature, lr=lr,
-                     hutchinson_samples=hutchinson_samples,
-                     projection=projection, ns_iters=ns_iters,
-                     overlap=overlap),
-                 mesh=mesh, axis_name=axis_name,
-                 controller=controller, cost=cost)
-
-
-def run_ranl_sharded2d(problem, key, *, mesh, num_rounds: int = 30,
-                       num_regions: int = 8,
-                       policy: PolicyConfig = PolicyConfig(),
-                       mu: float | None = None, curvature: str = "dense",
-                       lr: float = 1.0, use_kernel: bool = True,
-                       hutchinson_samples: int = 8,
-                       data_axis: str = "data", model_axis: str = "model",
-                       ns_iters: int | str = 60, overlap: bool = False,
-                       controller=None, cost=None):
-    """Deprecated: use ``repro.run(problem, key, engine="sharded2d", ...)``.
-    """
-    _deprecated("run_ranl_sharded2d", "sharded2d")
-    from ..api import run
-    return run(problem, key, engine="sharded2d",
-               options=RanlOptions(
-                   num_rounds=num_rounds, num_regions=num_regions,
-                   policy=policy, mu=mu, curvature=curvature, lr=lr,
-                   use_kernel=use_kernel,
-                   hutchinson_samples=hutchinson_samples,
-                   ns_iters=ns_iters, overlap=overlap),
-               mesh=mesh, data_axis=data_axis, model_axis=model_axis,
-               controller=controller, cost=cost)
-
-
-def lower_ranl_sharded2d(problem, key, *, mesh, num_rounds: int = 30,
-                         num_regions: int = 8,
-                         policy: PolicyConfig = PolicyConfig(),
-                         mu: float | None = None, curvature: str = "dense",
-                         lr: float = 1.0, use_kernel: bool = True,
-                         hutchinson_samples: int = 8,
-                         data_axis: str = "data",
-                         model_axis: str = "model",
-                         ns_iters: int | str = 60,
-                         overlap: bool = False, controller=None,
-                         cost=None):
-    """Deprecated: use ``repro.lower(problem, key, engine="sharded2d",
-    ...)``."""
-    _deprecated("lower_ranl_sharded2d", "sharded2d")
-    from ..api import lower
-    return lower(problem, key, engine="sharded2d",
-                 options=RanlOptions(
-                     num_rounds=num_rounds, num_regions=num_regions,
-                     policy=policy, mu=mu, curvature=curvature, lr=lr,
-                     use_kernel=use_kernel,
-                     hutchinson_samples=hutchinson_samples,
-                     ns_iters=ns_iters, overlap=overlap),
-                 mesh=mesh, data_axis=data_axis, model_axis=model_axis,
-                 controller=controller, cost=cost)
-
-
-def run_ranl_reference(problem, key, *, num_rounds: int = 30,
-                       num_regions: int = 8,
-                       policy: PolicyConfig = PolicyConfig(),
-                       mu: float | None = None, record_every: int = 1,
-                       controller=None, cost=None):
-    """Deprecated: use ``repro.run(problem, key, engine="reference", ...)``.
-    """
-    _deprecated("run_ranl_reference", "reference")
-    from ..api import run
-    return run(problem, key, engine="reference",
-               options=RanlOptions(
-                   num_rounds=num_rounds, num_regions=num_regions,
-                   policy=policy, mu=mu, record_every=record_every),
-               controller=controller, cost=cost)

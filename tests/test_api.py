@@ -1,12 +1,5 @@
-"""The unified dispatcher surface: ``repro.run`` / ``repro.lower``,
-``RanlOptions`` construction-time validation, and the five legacy
-entrypoints as bit-exact deprecation shims.
-
-The shim tests are the ONLY in-repo callers of the old entrypoints, and
-they catch the warning with ``pytest.warns`` — pyproject's
-``error::repro.core.options.EngineDeprecationWarning`` filter turns any
-other legacy call in the suite into a hard failure.
-"""
+"""The unified dispatcher surface: ``repro.run`` / ``repro.lower`` and
+``RanlOptions`` construction-time validation."""
 
 import jax
 import jax.numpy as jnp
@@ -14,11 +7,7 @@ import numpy as np
 import pytest
 
 import repro
-from repro.core import (PolicyConfig, lower_ranl_sharded,
-                        lower_ranl_sharded2d, make_quadratic, run_ranl,
-                        run_ranl_batch, run_ranl_reference,
-                        run_ranl_sharded, run_ranl_sharded2d)
-from repro.core.options import EngineDeprecationWarning
+from repro.core import PolicyConfig, make_quadratic
 from repro.hetero import PolicyController, QuorumController
 
 KEY = jax.random.PRNGKey(0)
@@ -180,75 +169,3 @@ def test_make_controller_quorum_spec():
         (0.8, 0.25, 3, 2)
     assert type(c.inner).__name__ == "ResourceProportionalController"
     assert make_controller("quorum:tau=none").quorum_tau is None
-
-
-# ----------------------------------------------------------------- shims
-
-def test_shim_run_ranl_bit_exact():
-    prob = _problem()
-    pol = PolicyConfig(keep_prob=0.5, tau_star=1, heterogeneous=True)
-    with pytest.warns(EngineDeprecationWarning, match="run_ranl is"):
-        old = run_ranl(prob, KEY, num_rounds=8, num_regions=4, policy=pol,
-                       lr=0.9)
-    new = repro.run(prob, KEY, engine="scan", num_rounds=8, num_regions=4,
-                    policy=pol, lr=0.9)
-    _same_result(old, new)
-
-
-def test_shim_run_ranl_batch_bit_exact():
-    prob = _problem()
-    keys = jax.random.split(KEY, 4)
-    with pytest.warns(EngineDeprecationWarning):
-        old = run_ranl_batch(prob, keys, num_rounds=6, num_regions=4,
-                             curvature="diag")
-    new = repro.run(prob, keys, engine="batch", num_rounds=6,
-                    num_regions=4, curvature="diag")
-    _same_result(old, new)
-
-
-def test_shim_run_ranl_sharded_bit_exact():
-    prob = _problem()
-    mesh = _mesh1d()
-    with pytest.warns(EngineDeprecationWarning):
-        old = run_ranl_sharded(prob, KEY, mesh=mesh, num_rounds=6,
-                               num_regions=4, overlap=True)
-    new = repro.run(prob, KEY, engine="sharded", mesh=mesh, num_rounds=6,
-                    num_regions=4, overlap=True)
-    _same_result(old, new)
-
-
-def test_shim_run_ranl_sharded2d_bit_exact():
-    prob = _problem()
-    mesh = _mesh2d()
-    with pytest.warns(EngineDeprecationWarning):
-        old = run_ranl_sharded2d(prob, KEY, mesh=mesh, num_rounds=6,
-                                 num_regions=4, curvature="diag")
-    new = repro.run(prob, KEY, engine="sharded2d", mesh=mesh,
-                    num_rounds=6, num_regions=4, curvature="diag")
-    _same_result(old, new)
-
-
-def test_shim_run_ranl_reference_bit_exact():
-    prob = _problem()
-    with pytest.warns(EngineDeprecationWarning):
-        old = run_ranl_reference(prob, KEY, num_rounds=6, num_regions=4)
-    new = repro.run(prob, KEY, engine="reference", num_rounds=6,
-                    num_regions=4)
-    _same_result(old, new)
-
-
-def test_shim_lower_matches_repro_lower():
-    prob = _problem()
-    mesh1, mesh2 = _mesh1d(), _mesh2d()
-    with pytest.warns(EngineDeprecationWarning):
-        old1 = lower_ranl_sharded(prob, KEY, mesh=mesh1, num_rounds=4,
-                                  num_regions=4)
-    new1 = repro.lower(prob, KEY, engine="sharded", mesh=mesh1,
-                       num_rounds=4, num_regions=4)
-    assert old1.compile().as_text() == new1.compile().as_text()
-    with pytest.warns(EngineDeprecationWarning):
-        old2 = lower_ranl_sharded2d(prob, KEY, mesh=mesh2, num_rounds=4,
-                                    num_regions=4, curvature="diag")
-    new2 = repro.lower(prob, KEY, engine="sharded2d", mesh=mesh2,
-                       num_rounds=4, num_regions=4, curvature="diag")
-    assert old2.compile().as_text() == new2.compile().as_text()

@@ -104,6 +104,35 @@ def test_sharded_single_device_mesh_matches_scan():
     assert ov.tau_covered == sh.tau_covered
 
 
+@pytest.mark.parametrize("case", [
+    dict(),
+    dict(overlap=True),
+    dict(quorum=0.75),
+    dict(quorum=0.75, overlap=True),
+    dict(compression="int8"),
+], ids=["sync", "sync-overlap", "quorum", "quorum-overlap", "int8"])
+def test_sharded_placements_agree(case):
+    """The one sharded round loop in its two placements: a ("data",) mesh
+    (replicated rows, ``worker_grad``) and a 1x1 ("data","model") mesh
+    (row slices, ``worker_grad_rows``, step assembly) run the same
+    diag-curvature trajectory and diagnostics."""
+    prob = make_quadratic(KEY, num_workers=8, dim=48, kappa=80.0,
+                          coupling=0.0, num_regions=6, grad_noise=0.1,
+                          hess_noise=0.1)
+    pol = PolicyConfig(keep_prob=0.5, tau_star=1, heterogeneous=False)
+    kw = dict(num_rounds=8, num_regions=6, policy=pol, curvature="diag",
+              **case)
+    one = repro.run(prob, KEY, engine="sharded",
+                    mesh=make_mesh((1,), ("data",)), **kw)
+    two = repro.run(prob, KEY, engine="sharded2d",
+                    mesh=make_mesh((1, 1), ("data", "model")), **kw)
+    for field in ("xs", "coverage", "comm_floats"):
+        np.testing.assert_allclose(np.asarray(getattr(two, field)),
+                                   np.asarray(getattr(one, field)),
+                                   rtol=0, atol=1e-6, err_msg=field)
+    assert abs(two.tau_star - one.tau_star) <= 1e-6
+
+
 def test_sharded_mesh_validation_errors():
     prob = make_quadratic(KEY, num_workers=4, dim=16, kappa=10.0,
                           coupling=0.0, num_regions=4)
